@@ -40,6 +40,8 @@ def main() -> None:
                          "write the Chrome trace (chrome://tracing) here; "
                          "--json payloads gain a telemetry snapshot")
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     if args.trace:
         from repro import obs
         obs.enable(sync=True)

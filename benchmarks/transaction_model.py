@@ -1,9 +1,10 @@
 """Memory-transaction model shared by the paper-table benchmarks.
 
-This container has no GPU/TPU clock, so the paper's *effective bandwidth*
-tables are reproduced through exact transaction counting — the quantity the
-paper's coalescing argument is about (§2.2): an uncoalesced access touches a
-full segment per element, so
+This model gives the paper's *effective bandwidth* tables from exact
+transaction counting, valid on any backend (times come only from runs
+on the chip, DESIGN.md §7.4) — the quantity the paper's coalescing
+argument is about (§2.2): an uncoalesced access touches a full segment
+per element, so
 
     time(variant) = touched_bytes(variant) / BW
     effective_bw  = copy_time / variant_time

@@ -85,7 +85,8 @@ from ..core.tiling import (compute_tables, pairing_vector, plan_bmmc,
 from ..kernels import ref as _ref
 from ..kernels.bmmc_permute import (block_geometry, block_permute_tables,
                                     lane_geometry, lane_permute_tables,
-                                    plan_geometry, tiled_permute_bwd_tables,
+                                    plan_geometry, plan_tables,
+                                    tiled_permute_bwd_tables,
                                     tiled_permute_tables)
 from .ir import Bfly, CmpHalves, Expr, Map, Perm
 from .optimize import (COMPUTES, Program, FusedStage, _run_fused, cluster,
@@ -124,7 +125,7 @@ def engines() -> tuple:
 # ---------------------------------------------------------------------------
 
 @functools.lru_cache(maxsize=512)
-def _geom_executable(geometry: tuple, interpret: bool, batched: bool = False,
+def _geom_executable(geometry: tuple, batched: bool = False,
                      epilogue: tuple = (), map_fns: tuple = ()):
     """One jitted tiled-pass executable per (tile geometry, epilogue
     signature). Index/epilogue tables are arguments, so every stage
@@ -132,44 +133,39 @@ def _geom_executable(geometry: tuple, interpret: bool, batched: bool = False,
     the batch size: growing B re-specializes the jit trace but never
     adds a geometry entry."""
     return jax.jit(functools.partial(
-        tiled_permute_tables, geometry=geometry, interpret=interpret,
-        batched=batched, epilogue=epilogue, map_fns=map_fns))
+        tiled_permute_tables, geometry=geometry, batched=batched,
+        epilogue=epilogue, map_fns=map_fns))
 
 
 @functools.lru_cache(maxsize=512)
-def _geom_bwd_executable(geometry: tuple, interpret: bool,
-                         batched: bool = False, epilogue: tuple = (),
-                         map_fns: tuple = ()):
+def _geom_bwd_executable(geometry: tuple, batched: bool = False,
+                         epilogue: tuple = (), map_fns: tuple = ()):
     """One jitted gradient-megakernel executable per (tile geometry,
     epilogue signature) — the backward twin of :func:`_geom_executable`,
     same cache-key discipline (tables are runtime arguments)."""
     return jax.jit(functools.partial(
-        tiled_permute_bwd_tables, geometry=geometry, interpret=interpret,
-        batched=batched, epilogue=epilogue, map_fns=map_fns))
+        tiled_permute_bwd_tables, geometry=geometry, batched=batched,
+        epilogue=epilogue, map_fns=map_fns))
 
 
 @functools.lru_cache(maxsize=256)
-def _block_executable(geometry: tuple, interpret: bool,
-                      batched: bool = False):
+def _block_executable(geometry: tuple, batched: bool = False):
     """One jitted block-permute (grid-remapped DMA copy) executable per
     geometry; the source-row table is a runtime argument."""
     return jax.jit(functools.partial(
-        block_permute_tables, geometry=geometry, interpret=interpret,
-        batched=batched))
+        block_permute_tables, geometry=geometry, batched=batched))
 
 
 @functools.lru_cache(maxsize=256)
-def _lane_executable(geometry: tuple, interpret: bool,
-                     batched: bool = False):
+def _lane_executable(geometry: tuple, batched: bool = False):
     """One jitted lane-permute (in-VMEM row gather) executable per
     geometry; the lane table is a runtime argument."""
     return jax.jit(functools.partial(
-        lane_permute_tables, geometry=geometry, interpret=interpret,
-        batched=batched))
+        lane_permute_tables, geometry=geometry, batched=batched))
 
 
 def _pallas_engine(x: jax.Array, bmmc: Bmmc, *, t: Optional[int] = None,
-                   interpret: bool = True, batched: bool = False) -> jax.Array:
+                   batched: bool = False) -> jax.Array:
     from ..kernels import ops
 
     if bmmc.is_identity_perm():
@@ -187,14 +183,14 @@ def _pallas_engine(x: jax.Array, bmmc: Bmmc, *, t: Optional[int] = None,
     if kernel == "none":
         return x
     if kernel == "block":
-        run = _block_executable(block_geometry(payload), interpret, batched)
+        run = _block_executable(block_geometry(payload), batched)
         return run(x, payload.src_rows)
     if kernel == "lane":
-        run = _lane_executable(lane_geometry(payload), interpret, batched)
+        run = _lane_executable(lane_geometry(payload), batched)
         return run(x, payload.src_lane)
     for plan in payload:
-        run = _geom_executable(plan_geometry(plan), interpret, batched)
-        x = run(x, plan.in_rows, plan.out_rows, plan.xor_low, plan.src0)
+        run = _geom_executable(plan_geometry(plan), batched)
+        x = run(x, *plan_tables(plan))
     return x
 
 
@@ -318,7 +314,7 @@ def _fused_kernel_args(entries: tuple, dtype) -> tuple:
 
 
 def _fused_pallas(x: jax.Array, fs: FusedStage, t: int, *,
-                  interpret: bool = True, batched: bool = False) -> jax.Array:
+                  batched: bool = False) -> jax.Array:
     """Run one cluster as a double-buffered megakernel dispatch: the
     first tiled pass carries every fused compute as an in-VMEM epilogue;
     a second plain pass (general BMMCs only, §5.2) finishes the
@@ -326,13 +322,11 @@ def _fused_pallas(x: jax.Array, fs: FusedStage, t: int, *,
     plans, entries = _fused_plan_cached(fs, t)
     plan = plans[0]
     sig, scal, vmem, map_fns = _fused_kernel_args(entries, x.dtype)
-    run = _geom_executable(plan_geometry(plan), interpret, batched,
-                           sig, map_fns)
-    x = run(x, plan.in_rows, plan.out_rows, plan.xor_low, plan.src0,
-            epi_scalar=scal, epi_vmem=vmem)
+    run = _geom_executable(plan_geometry(plan), batched, sig, map_fns)
+    x = run(x, *plan_tables(plan), epi_scalar=scal, epi_vmem=vmem)
     for plan in plans[1:]:
-        run = _geom_executable(plan_geometry(plan), interpret, batched)
-        x = run(x, plan.in_rows, plan.out_rows, plan.xor_low, plan.src0)
+        run = _geom_executable(plan_geometry(plan), batched)
+        x = run(x, *plan_tables(plan))
     return x
 
 
@@ -511,15 +505,6 @@ def _pulled_back_fn(comp: Expr, prefix: Bmmc, batched: bool) -> tuple:
     return fwd, bwd
 
 
-def _bmmc_table(b: Bmmc) -> np.ndarray:
-    """``tab[i] = b.apply(i)`` vectorized over all ``2^n`` indices."""
-    idx = np.arange(1 << b.n, dtype=np.int64)
-    out = np.zeros_like(idx)
-    for j, row in enumerate(b.rows):
-        out |= _np_parity(idx & row) << j
-    return out ^ b.c
-
-
 _BwdPlan = collections.namedtuple(
     "_BwdPlan", ["n", "recs", "links", "segs", "final", "has_bfly"])
 
@@ -609,7 +594,8 @@ def _program_bwd_plan(prog: Program, batched: bool):
         rec_id = len(recs)
         fwds = tuple(_pulled_back_fn(c, p, batched)[0] for c, p in comps)
         recs.append([res_of[si], fwds, False])
-        tau_tab = _bmmc_table(sigma.inverse())  # Y index -> link-space index
+        # Y index -> link-space index
+        tau_tab = sigma.inverse().apply_all(np.arange(1 << n, dtype=np.int64))
         a_off = sigma.apply(0)
         for j in range(len(comps) - 1, -1, -1):
             comp, prefix = comps[j]
@@ -784,20 +770,15 @@ def _collapsed_bwd(plan, res, ct, engine, batched):
 def _fused_bwd_kernel_plan(fs: FusedStage, t: int):
     """Offline artifacts of the gradient megakernel for one cluster, or
     None when it can't run at this tile parameter: the forward plan +
-    epilogue entries (shared tables), the inverse ``src0`` gather table
-    (``inv[src0[j]] = j``; the per-tile XOR folds into the lookup at
-    kernel time), and the inverse plans of any trailing plain passes
-    (§5.2 two-pass factorizations — undone pass-by-pass before the
-    gradient kernel, keeping the backward round-trip count equal to the
-    forward's)."""
+    epilogue entries (shared tables; the kernel un-permutes through the
+    plan's ``intra_inv`` factorization), and the inverse plans of any
+    trailing plain passes (§5.2 two-pass factorizations — undone
+    pass-by-pass before the gradient kernel, keeping the backward
+    round-trip count equal to the forward's)."""
     got = _fused_plan_cached(fs, t)
     if got is None:
         return None
     plans, entries = got
-    p = plans[0].src0.reshape(-1)
-    inv_src0 = np.empty_like(p)
-    inv_src0[p] = np.arange(p.size, dtype=p.dtype)
-    inv_src0 = inv_src0.reshape(plans[0].src0.shape)
     extra = []
     for pass_plan in plans[1:]:
         try:
@@ -806,22 +787,22 @@ def _fused_bwd_kernel_plan(fs: FusedStage, t: int):
             return None
         if len(extra[-1]) != 1:
             return None  # inverse pass count must mirror the forward's
-    return plans, entries, inv_src0, tuple(extra)
+    return plans, entries, tuple(extra)
 
 
-def _fused_bwd_pallas(fs, t, batched, x, ct, *, interpret=True):
+def _fused_bwd_pallas(fs, t, batched, x, ct):
     """One-kernel cluster backward: undo the trailing plain passes, then
     dispatch the gradient megakernel over the forward's own plan."""
-    plans, entries, inv_src0, extra = _fused_bwd_kernel_plan(fs, t)
+    plans, entries, extra = _fused_bwd_kernel_plan(fs, t)
     for inv_plans in reversed(extra):
         for p in inv_plans:
-            run = _geom_executable(plan_geometry(p), interpret, batched)
-            ct = run(ct, p.in_rows, p.out_rows, p.xor_low, p.src0)
+            run = _geom_executable(plan_geometry(p), batched)
+            ct = run(ct, *plan_tables(p))
     plan = plans[0]
     sig, scal, vmem, map_fns = _fused_kernel_args(entries, x.dtype)
-    run = _geom_bwd_executable(plan_geometry(plan), interpret, batched,
+    run = _geom_bwd_executable(plan_geometry(plan, inverse=True), batched,
                                sig, map_fns)
-    return run(x, ct, plan.in_rows, plan.out_rows, plan.xor_low, inv_src0,
+    return run(x, ct, *plan_tables(plan, inverse=True),
                epi_scalar=scal, epi_vmem=vmem)
 
 
@@ -857,7 +838,7 @@ def _fused_bwd_impl(fs, engine, batched, x, ct):
         t = _fused_tile(x, fs, batched)
         if t is not None and _fused_bwd_kernel_plan(fs, t) is not None:
             if _otrace._state.enabled:
-                plans, _, _, extra = _fused_bwd_kernel_plan(fs, t)
+                plans, _, extra = _fused_bwd_kernel_plan(fs, t)
                 rt = 1 + sum(len(ip) for ip in extra)
                 _ometrics.inc("dispatch.kernel", kernel="fused")
                 _ometrics.inc("model.round_trips", rt)
@@ -1408,7 +1389,7 @@ class CompiledExpr:
         from .. import guard as _g
         if _g.enabled():
             from ..guard import runtime as _grt
-            if _grt._trace_state_clean():
+            if _grt._trace_state_clean(x):
                 # ring 2: guarded dispatch — program + in-program
                 # probes in one executable (wrapping the inner jitted
                 # _program_executable, so the cache/telemetry contracts

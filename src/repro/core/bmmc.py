@@ -15,6 +15,8 @@ import dataclasses
 import random
 from typing import Optional, Sequence
 
+import numpy as np
+
 from . import f2
 
 
@@ -89,6 +91,13 @@ class Bmmc:
     def apply(self, x: int) -> int:
         """y = A x ^ c for a single integer index."""
         return f2.matvec(self.rows, x) ^ self.c
+
+    def apply_all(self, xs: np.ndarray) -> np.ndarray:
+        """:meth:`apply` over an int64 array of indices."""
+        out = np.full_like(xs, self.c)
+        for i, row in enumerate(self.rows):
+            out ^= (np.bitwise_count(xs & row) & 1).astype(xs.dtype) << i
+        return out
 
     def verify(self) -> "Bmmc":
         """Re-prove well-formedness (bit ranges + F2 rank) through the

@@ -31,6 +31,7 @@ tests/test_distributed.py.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, Optional, Union
 
 import numpy as np
@@ -259,58 +260,55 @@ def binary_mesh(s: int):
     """Mesh of 2^s devices as s binary axes sb{s-1}..sb0 (msb first)."""
     import jax
     names = tuple(f"sb{m}" for m in reversed(range(s)))
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):  # absent before jax 0.5
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * s
-    return jax.make_mesh((2,) * s, names, **kw)
+    return jax.make_mesh((2,) * s, names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * s)
 
 
-def run_plan(x, plan: List[Round], s: int, mesh=None):
-    """Apply a distributed BMMC plan to ``x`` (shape (2^n,) or (2^n, d))."""
+def _parity_bits(v, rows, nbits: int):
+    """``rows . v`` over F2, elementwise on a uint32 array (bit i of the
+    result is the parity of ``v & rows[i]``)."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
+    out = jnp.zeros_like(v)
+    for i, row in enumerate(rows[:nbits]):
+        bit = jax.lax.population_count(v & jnp.uint32(row)) & 1
+        out = out | (bit << i)
+    return out
 
-    mesh = mesh or binary_mesh(s)
+
+def _shard_spec(s: int, ndim: int):
+    """The leading axis split over all s binary mesh axes, msb first."""
+    from jax.sharding import PartitionSpec as P
     names_msb = tuple(f"sb{m}" for m in reversed(range(s)))
-    spec = P(names_msb) if x.ndim == 1 else P(names_msb, None)
-    nl = int(np.log2(x.shape[0])) - s
+    return P(names_msb) if ndim == 1 else P(names_msb, None)
+
+
+@functools.lru_cache(maxsize=64)
+def _plan_executable(plan: tuple, s: int, nl: int, ndim: int, mesh):
+    """One jitted shard_map program per (plan, shard geometry, mesh)."""
+    import jax
+    import jax.numpy as jnp
+
+    names_msb = tuple(f"sb{m}" for m in reversed(range(s)))
 
     def shard_fn(xs):
-        def my_shard():
-            sig = jnp.zeros((), jnp.int32)
-            for m in range(s):
-                sig = sig | (jax.lax.axis_index(f"sb{m}").astype(jnp.int32) << m)
-            return sig
+        sig = jnp.zeros((), jnp.uint32)
+        for m in range(s):
+            sig = sig | (jax.lax.axis_index(f"sb{m}").astype(jnp.uint32) << m)
 
         for r in plan:
             if isinstance(r, LocalRound):
                 inv = f2.inverse(r.rows)
-                y = np.arange(1 << nl, dtype=np.uint32)
-                base = np.zeros_like(y)
-                for i, row in enumerate(inv):
-                    base |= ((np.bitwise_count(y & np.uint32(row)) & 1)
-                             .astype(np.uint32)) << np.uint32(i)
                 # dynamic complement: c_eff = c ^ (ls . shard);
-                # src[y] = inv.(y ^ c_eff) = base[y] ^ inv.c_eff
-                sig = my_shard()
-                c_eff = jnp.uint32(r.c)
-                for i in range(nl):
-                    bit = jax.lax.population_count(
-                        jnp.uint32(sum(((r.ls_rows[i] >> b) & 1) << b
-                                       for b in range(s))) &
-                        sig.astype(jnp.uint32)) & 1
-                    c_eff = c_eff ^ (bit.astype(jnp.uint32) << i)
-                inv_c = jnp.zeros((), jnp.uint32)
-                for i, row in enumerate(inv):
-                    bit = jax.lax.population_count(jnp.uint32(row) & c_eff) & 1
-                    inv_c = inv_c | (bit.astype(jnp.uint32) << i)
-                src = jnp.asarray(base) ^ inv_c
+                # src[y] = inv.(y ^ c_eff) = inv.y ^ inv.c_eff
+                c_eff = jnp.uint32(r.c) ^ _parity_bits(
+                    sig, [row & ((1 << s) - 1) for row in r.ls_rows], nl)
+                y = jax.lax.iota(jnp.uint32, 1 << nl)
+                src = _parity_bits(y, inv, nl) ^ _parity_bits(c_eff, inv, nl)
                 xs = jnp.take(xs, src.astype(jnp.int32), axis=0)
             elif isinstance(r, PermuteRound):
-                pairs = [(sig, f2.matvec(r.rows, sig) ^ r.c)
-                         for sig in range(1 << s)]
+                pairs = [(g, f2.matvec(r.rows, g) ^ r.c)
+                         for g in range(1 << s)]
                 xs = jax.lax.ppermute(xs, names_msb, pairs)
             else:  # ExchangeRound
                 k = r.k
@@ -322,9 +320,20 @@ def run_plan(x, plan: List[Round], s: int, mesh=None):
                 xs = xs2.reshape((1 << nl,) + tail)
         return xs
 
-    fn = shard_map(shard_fn, mesh=mesh, in_specs=spec, out_specs=spec)
+    spec = _shard_spec(s, ndim)
+    return jax.jit(jax.shard_map(shard_fn, mesh=mesh, in_specs=spec,
+                                 out_specs=spec))
+
+
+def run_plan(x, plan: List[Round], s: int, mesh=None):
+    """Apply a distributed BMMC plan to ``x`` (shape (2^n,) or (2^n, d))."""
+    import jax
     from jax.sharding import NamedSharding
-    x = jax.device_put(x, NamedSharding(mesh, spec))
+
+    mesh = mesh or binary_mesh(s)
+    nl = int(np.log2(x.shape[0])) - s
+    fn = _plan_executable(tuple(plan), s, nl, x.ndim, mesh)
+    x = jax.device_put(x, NamedSharding(mesh, _shard_spec(s, x.ndim)))
     return fn(x)
 
 

@@ -11,16 +11,19 @@ partitioned into:
 * thread-block bits — the rest (``n_TB = n - 2t + n_over``), all >= t.
 
 One tile = all index combinations of (L ∪ R) bits with the block bits fixed:
-``2^(t - n_over)`` full input rows, mapping onto ``2^(t - n_over)`` full
-output rows. This module precomputes, per permutation (offline, matching the
-paper's codegen setting):
+``2^(t - n_over)`` full input rows, mapping onto as many full output rows.
+The lowest ``n_over`` block bits are folded into the tile as well, so a
+tile is ``2^t`` rows whenever ``2t <= n`` (``_square_up``). This module
+precomputes, per permutation (offline, matching the paper's codegen
+setting):
 
 * ``in_rows[g, r]``   — input row id read by tile ``g`` (row view: (2^(n-t), 2^t)),
 * ``out_rows[g, r']`` — output row id written by tile ``g``,
 * ``xor_low[g]``      — per-tile XOR on the intra-tile lane gather (the
   block-bit contribution to the low output bits; 0 for every BPC),
 * ``src0``            — flat intra-tile gather table for tile 0:
-  ``out_tile.flat[j] = in_tile.flat[src0[j ^ xor_low[g]]]``.
+  ``out_tile.flat[j] = in_tile.flat[src0[j ^ xor_low[g]]]``; the kernel
+  applies it in the factored form :class:`IntraMap`.
 
 The per-tile XOR trick is the TPU replacement for re-deriving indices per
 thread: tables are computed once; the kernel's scalar core only reads them.
@@ -28,6 +31,8 @@ thread: tables are computed once; the kernel's scalar core only reads them.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import random
 from typing import Optional
 
 import numpy as np
@@ -45,13 +50,19 @@ def _scatter_bits(value: int, positions: list) -> int:
     return out
 
 
-def _gather_bits(value: int, positions: list) -> int:
-    """Collect bits of ``value`` at ``positions`` into a compact int."""
-    out = 0
+def _gather_all(xs: np.ndarray, positions: list) -> np.ndarray:
+    """Bit ``k`` of each result is bit ``positions[k]`` of ``xs``."""
+    out = np.zeros_like(xs)
     for k, pos in enumerate(positions):
-        if (value >> pos) & 1:
-            out |= 1 << k
+        out |= ((xs >> pos) & 1) << k
     return out
+
+
+def _tile0_sources(bmmc: Bmmc, out_rows0: np.ndarray, t: int) -> np.ndarray:
+    """Input index of every (output row slot, lane) of tile 0."""
+    ys = ((out_rows0.astype(np.int64)[:, None] << t)
+          | np.arange(1 << t, dtype=np.int64)[None, :])
+    return bmmc.inverse().apply_all(ys)
 
 
 def _run_length(rows: np.ndarray) -> int:
@@ -135,11 +146,139 @@ class TilePlan:
         audit_tile_plan(self)
         return self
 
+    @functools.cached_property
+    def intra(self) -> "IntraMap":
+        """The kernel's factorization of the ``src0``/``xor_low`` gather."""
+        m, ks = _intra_affine(self.src0, self.xor_low)
+        return factor_intra(m, ks, self.rows_per_tile, self.row_len)
 
-def plan_tiled(bmmc: Bmmc, t: int) -> Optional[TilePlan]:
-    """Build a TilePlan, or None if ``bmmc`` is not tiled for this ``t``."""
+    @functools.cached_property
+    def intra_inv(self) -> "IntraMap":
+        """Factorization of the inverse gather (the gradient kernel's
+        un-permute of the cotangent tile)."""
+        m, ks = _intra_affine(self.src0, self.xor_low)
+        minv = f2.inverse(m)
+        return factor_intra(minv, [f2.matvec(minv, k) for k in ks],
+                            self.rows_per_tile, self.row_len)
+
+
+# ---------------------------------------------------------------------------
+# Intra-tile factorization (the TPU form of the src0 gather).
+#
+# Tile slot (r, c) — row r of p bits, lane c of t bits, flat index
+# r * 2^t + c — of tile g gathers from slot ``M (r, c) ^ K_g``: the
+# src0 table is affine (M, K_0) and xor_low[g] only moves the constant.
+# A vector unit has no general in-memory gather, so the kernel applies M
+# as four steps, each of which lowers to rolls, selects and one-hot
+# matmuls (gather form: step i reads z_i[u] = z_{i-1}[M_i u]):
+#
+#   1. row shear     z[r, c] = w[r ^ F1 c, c]
+#   2. row/lane maps z[r, c] = w[S r ^ kr, D c ^ kc]   (one-hot matmuls)
+#   3. lane shear    z[r, c] = w[r, c ^ E r]
+#   4. row shear     z[r, c] = w[r ^ F0 c, c]
+#
+# With M = [[A, B], [C, D0]] (row/lane blocks): pick F0 so that
+# D = D0 ^ C F0 is invertible (F0 = 0 when D0 already is), then
+# F1 = B' D^-1, S = A ^ F1 C, E = D^-1 C with B' = B ^ A F0. The
+# per-tile constant rides step 2: (kr, kc) = M1 K_g.
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class IntraMap:
+    """Kernel tables of one intra-tile affine gather.
+
+    ``steps`` flags which of (row shear 1, row map, lane map, lane shear,
+    row shear 2) do work — part of the executable key. ``words`` holds
+    their parameters as ``f1 (p) | sinv (p) | dcol (t) | e (t) | f0
+    (p)`` bit masks; ``ktab[g]`` packs tile g's ``S^-1 kr << t | kc``.
+    """
+
+    steps: tuple
+    words: np.ndarray     # (3p + 2t,) int32
+    ktab: np.ndarray      # (n_tiles,) int32
+
+
+def _intra_affine(src0: np.ndarray, xor_low: np.ndarray) -> tuple:
+    """``(M, [K_g])``: src0 as an affine map on the flat slot index
+    (rows representation over p + t bits) and every tile's constant."""
+    flat = src0.reshape(-1).astype(np.int64)
+    nbits = flat.size.bit_length() - 1
+    k0 = int(flat[0])
+    cols = [int(flat[1 << j]) ^ k0 for j in range(nbits)]
+    idx = np.arange(flat.size, dtype=np.int64)
+    pred = np.full_like(idx, k0)
+    for j, col in enumerate(cols):
+        pred ^= ((idx >> j) & 1) * col
+    if not np.array_equal(pred, flat):
+        raise ValueError("intra-tile gather table is not affine")
+    m = tuple(sum(((col >> i) & 1) << j for j, col in enumerate(cols))
+              for i in range(nbits))
+    return m, [k0 ^ f2.matvec(m, int(x)) for x in xor_low]
+
+
+def _madd(a, b) -> tuple:
+    return tuple(x ^ y for x, y in zip(a, b))
+
+
+def factor_intra(m: tuple, ks: list, rows: int, row_len: int) -> IntraMap:
+    """Factor the gather ``z[u] = w[M u ^ K_g]`` on a (rows, row_len)
+    tile into the kernel's four steps (see the block comment above)."""
+    t = row_len.bit_length() - 1
+    p = rows.bit_length() - 1
+    tmask, pmask = (1 << t) - 1, (1 << p) - 1
+    a = tuple((m[t + i] >> t) & pmask for i in range(p))
+    b = tuple(m[t + i] & tmask for i in range(p))
+    c = tuple(m[i] >> t for i in range(t))
+    d0 = tuple(m[i] & tmask for i in range(t))
+    if not f2.is_invertible(m):
+        raise ValueError("intra-tile gather is not a bijection")
+    f0 = (0,) * p
+    rng = random.Random(0)
+    while not f2.is_invertible(_madd(d0, f2.matmul(c, f0))):
+        # [C D0] has full row rank, so a random F0 lands on an
+        # invertible D with probability > 1/4 per draw
+        f0 = tuple(rng.getrandbits(t) for _ in range(p))
+    d = _madd(d0, f2.matmul(c, f0))
+    dinv = f2.inverse(d)
+    f1 = f2.matmul(_madd(b, f2.matmul(a, f0)), dinv)
+    s = _madd(a, f2.matmul(f1, c))
+    e = f2.matmul(dinv, c)
+    sinv = f2.inverse(s)
+    ktab = np.empty((len(ks),), dtype=np.int32)
+    for g, k in enumerate(ks):
+        kc = k & tmask
+        kr = f2.matvec(sinv, (k >> t) ^ f2.matvec(f1, kc))
+        ktab[g] = (kr << t) | kc
+    words = np.asarray(
+        list(f1) + [f2.column(sinv, i) for i in range(p)]
+        + [f2.column(d, i) for i in range(t)] + list(e) + list(f0),
+        dtype=np.int32)
+    steps = (any(f1), s != f2.identity(p) or bool((ktab >> t).any()),
+             d != f2.identity(t) or bool((ktab & tmask).any()),
+             any(e), any(f0))
+    return IntraMap(steps=steps, words=words, ktab=ktab)
+
+
+def _square_up(tb: list, deficit: int) -> tuple:
+    """Split the thread-block positions into (absorbed, remaining).
+
+    A tile spans ``2^(t - deficit)`` rows of its own; the lowest
+    ``deficit`` thread-block positions are folded into it so every tile
+    is ``2^t x 2^t`` whenever ``2t <= n`` (fewer only for t > n/2). A
+    square tile keeps the kernel's row axis a whole number of sublane
+    tiles and gives one tile geometry per ``t``, independent of how many
+    witness bits overlap the lanes."""
+    k = min(deficit, len(tb))
+    return tb[:k], tb[k:]
+
+
+def _classic_layout(bmmc: Bmmc, t: int) -> Optional[tuple]:
+    """``(cols, n_over, row_pos, out_pos, tb)`` of the classic tiled plan:
+    tile slot ``r`` reads input row bits ``row_pos``, output slot ``r'``
+    enumerates ``out_pos``, and ``tb`` indexes the tiles. None if
+    ``bmmc`` is not tiled for this ``t``."""
     n = bmmc.n
-    if 2 * t > n + t:  # t > n: nonsensical
+    if t > n:
         return None
     cols = bmmc.tiled_columns(t)
     if cols is None:
@@ -152,47 +291,49 @@ def plan_tiled(bmmc: Bmmc, t: int) -> Optional[TilePlan]:
     r_not_l = sorted(r_set - low)           # t - n_over positions, all >= t
     l_not_r = sorted(low - r_set)           # t - n_over positions, all < t
     tb = sorted(set(range(n)) - low - r_set)
-    n_tb = len(tb)
-    assert n_tb == n - 2 * t + n_over
+    assert len(tb) == n - 2 * t + n_over
+    extra, tb = _square_up(tb, n_over)
+    return (cols, n_over, sorted(r_not_l + extra), l_not_r + extra, tb)
 
-    rpt = 1 << (t - n_over)                  # rows per tile
-    n_tiles = 1 << n_tb
+
+def plan_tiled(bmmc: Bmmc, t: int) -> Optional[TilePlan]:
+    """Build a TilePlan, or None if ``bmmc`` is not tiled for this ``t``."""
+    lay = _classic_layout(bmmc, t)
+    if lay is None:
+        return None
+    cols, n_over, row_pos, out_pos, tb = lay
+    rpt = 1 << len(row_pos)                  # rows per tile
+    n_tiles = 1 << len(tb)
     row_len = 1 << t
     low_mask = row_len - 1
-
-    ainv = bmmc.inverse()
 
     in_rows = np.empty((n_tiles, rpt), dtype=np.int32)
     out_rows = np.empty((n_tiles, rpt), dtype=np.int32)
     xor_low = np.empty((n_tiles,), dtype=np.int32)
 
     # Row tables. y_high = A[t:, :] x ^ c_high depends only on non-R bits of x
-    # (the zero block kills R), i.e. on (L\R, TB): enumerate r' over L\R.
+    # (the zero block kills R), i.e. on (L\R, absorbed TB, TB).
     for g in range(n_tiles):
         base = _scatter_bits(g, tb)
         delta = f2.matvec(bmmc.rows, base)
         xor_low[g] = delta & low_mask
         for r in range(rpt):
-            in_rows[g, r] = (base | _scatter_bits(r, r_not_l)) >> t
+            in_rows[g, r] = (base | _scatter_bits(r, row_pos)) >> t
         for rp in range(rpt):
-            y = bmmc.apply(base | _scatter_bits(rp, l_not_r))
+            y = bmmc.apply(base | _scatter_bits(rp, out_pos))
             out_rows[g, rp] = y >> t
 
     # Intra-tile gather table for tile 0 (other tiles differ by xor_low only).
-    src0 = np.empty((rpt, row_len), dtype=np.int32)
-    for rp in range(rpt):
-        y_hi = int(out_rows[0, rp]) << t
-        for cp in range(row_len):
-            x = ainv.apply(y_hi | cp)
-            assert _gather_bits(x, tb) == 0, "tile-0 source must be in tile 0"
-            r = _gather_bits(x, r_not_l)
-            src0[rp, cp] = r * row_len + (x & low_mask)
+    xs = _tile0_sources(bmmc, out_rows[0], t)
+    assert not _gather_all(xs, tb).any(), "tile-0 source must be in tile 0"
+    src0 = (_gather_all(xs, row_pos) * row_len
+            + (xs & low_mask)).astype(np.int32)
     return TilePlan(
         bmmc=bmmc, t=t, row_cols=tuple(sorted(cols)), n_over=n_over,
         tb_positions=tuple(tb), in_rows=in_rows, out_rows=out_rows,
         xor_low=xor_low, src0=src0,
         in_run=_run_length(in_rows), out_run=_run_length(out_rows),
-        row_dirs=tuple(1 << p for p in r_not_l),
+        row_dirs=tuple(1 << p for p in row_pos),
     )
 
 
@@ -278,23 +419,35 @@ def _out_low_positions(bmmc: Bmmc, t: int, count: int) -> list:
     return chosen
 
 
-def plan_general(bmmc: Bmmc, t: int) -> Optional[TilePlan]:
-    """One-pass plan for an arbitrary invertible BMMC (see block comment
-    above). Returns None when the tile would exceed the array
+def _general_layout(bmmc: Bmmc, t: int) -> Optional[tuple]:
+    """``(a, row_dirs, out_pos, tb)`` of the generalized plan: the
+    witness directions plus the absorbed thread-block unit directions,
+    the positions enumerating a tile's output rows, and the remaining
+    thread-block positions. None when the tile would exceed the array
     (``n - 2t + a < 0``, only possible for t > n/2)."""
     n = bmmc.n
     if not 0 < t <= n:
         return None
-    low_mask = (1 << t) - 1
     a, row_dirs = _split_directions(bmmc, t)
     if n - 2 * t + a < 0:
         return None
-    tb = _tb_complement(row_dirs, t, n)
-    rpt = 1 << (t - a)
+    extra, tb = _square_up(_tb_complement(row_dirs, t, n), a)
+    out_pos = _out_low_positions(bmmc, t, t - a) + extra
+    return a, row_dirs + [1 << p for p in extra], out_pos, tb
+
+
+def plan_general(bmmc: Bmmc, t: int) -> Optional[TilePlan]:
+    """One-pass plan for an arbitrary invertible BMMC (see block comment
+    above). Returns None when the tile would exceed the array
+    (``n - 2t + a < 0``, only possible for t > n/2)."""
+    lay = _general_layout(bmmc, t)
+    if lay is None:
+        return None
+    a, row_dirs, out_pos, tb = lay
+    low_mask = (1 << t) - 1
+    rpt = 1 << len(row_dirs)
     n_tiles = 1 << len(tb)
     row_len = 1 << t
-    chosen_low = _out_low_positions(bmmc, t, t - a)
-    ainv = bmmc.inverse()
 
     in_rows = np.empty((n_tiles, rpt), dtype=np.int32)
     out_rows = np.empty((n_tiles, rpt), dtype=np.int32)
@@ -305,19 +458,16 @@ def plan_general(bmmc: Bmmc, t: int) -> Optional[TilePlan]:
         for r in range(rpt):
             in_rows[g, r] = (base ^ _xor_dirs(r, row_dirs)) >> t
         for rp in range(rpt):
-            y = bmmc.apply(base ^ _scatter_bits(rp, chosen_low))
+            y = bmmc.apply(base ^ _scatter_bits(rp, out_pos))
             out_rows[g, rp] = y >> t
 
-    slot_of_row = {int(in_rows[0, r]): r for r in range(rpt)}
-    assert len(slot_of_row) == rpt, "tile rows must be distinct"
-    src0 = np.empty((rpt, row_len), dtype=np.int32)
-    for rp in range(rpt):
-        y_hi = int(out_rows[0, rp]) << t
-        for cp in range(row_len):
-            x = ainv.apply(y_hi | cp)
-            r = slot_of_row.get(x >> t)
-            assert r is not None, "tile-0 source must be in tile 0"
-            src0[rp, cp] = r * row_len + (x & low_mask)
+    order = np.argsort(in_rows[0])
+    rows0 = in_rows[0][order]
+    assert np.unique(rows0).size == rpt, "tile rows must be distinct"
+    xs = _tile0_sources(bmmc, out_rows[0], t)
+    pos = np.minimum(np.searchsorted(rows0, xs >> t), rpt - 1)
+    assert np.array_equal(rows0[pos], xs >> t), "tile-0 source must be in tile 0"
+    src0 = (order[pos] * row_len + (xs & low_mask)).astype(np.int32)
     return TilePlan(
         bmmc=bmmc, t=t, row_cols=(), n_over=a, tb_positions=tuple(tb),
         in_rows=in_rows, out_rows=out_rows, xor_low=xor_low, src0=src0,
@@ -463,32 +613,12 @@ class PlanStats:
                 self.out_run * self.row_len * itemsize)
 
 
-def plan_stats(bmmc: Bmmc, t: int) -> Optional[PlanStats]:
-    """Analytic counterpart of ``plan_tiled`` (no per-tile enumeration)."""
-    n = bmmc.n
-    cols = bmmc.tiled_columns(t)
-    if cols is None:
-        return None
-    low = set(range(t))
-    r_set = set(cols)
-    n_over = len(r_set & low)
-    if n - 2 * t + n_over < 0:
-        return None
-    r_not_l = sorted(r_set - low)
-    l_not_r = sorted(low - r_set)
-    tb = sorted(set(range(n)) - low - r_set)
-    rpt = 1 << (t - n_over)
-
-    # input-run: rows consecutive iff the low R\L positions are t, t+1, ...
-    k_in = 0
-    while k_in < len(r_not_l) and r_not_l[k_in] == t + k_in:
-        k_in += 1
-
-    # output-run: out_rows[g, r'] = (A (base|scatter(r')) ^ c) >> t, affine in
-    # the r' bits. Runs of 2^k are consecutive iff bit i of r' moves y_high
-    # by exactly 2^i for i < k and no other contribution (base bits, c)
-    # touches the low k bits of y_high.
-    deltas = [f2.matvec(bmmc.rows, 1 << pos) >> t for pos in l_not_r]
+def _out_run_bits(bmmc: Bmmc, t: int, out_pos: list, tb: list) -> int:
+    """log2 of the output DMA run: ``out_rows[g, r']`` is affine in the
+    bits of ``r'``; runs of 2^k are consecutive iff bit i of r' moves
+    y_high by exactly 2^i for i < k and no other contribution (higher
+    slot bits, base bits, c) touches the low k bits of y_high."""
+    deltas = [f2.matvec(bmmc.rows, 1 << pos) >> t for pos in out_pos]
     others = [f2.matvec(bmmc.rows, 1 << pos) >> t for pos in tb]
     others.append(bmmc.c >> t)
     k_out = 0
@@ -501,23 +631,31 @@ def plan_stats(bmmc: Bmmc, t: int) -> Optional[PlanStats]:
         if not ok:
             break
         k_out = k
-    return PlanStats(n=n, t=t, n_over=n_over, n_tiles=1 << len(tb),
-                     rows_per_tile=rpt, row_len=1 << t,
+    return k_out
+
+
+def plan_stats(bmmc: Bmmc, t: int) -> Optional[PlanStats]:
+    """Analytic counterpart of ``plan_tiled`` (no per-tile enumeration)."""
+    lay = _classic_layout(bmmc, t)
+    if lay is None:
+        return None
+    _, n_over, row_pos, out_pos, tb = lay
+    # input-run: rows consecutive iff the low row positions are t, t+1, ...
+    k_in = 0
+    while k_in < len(row_pos) and row_pos[k_in] == t + k_in:
+        k_in += 1
+    k_out = _out_run_bits(bmmc, t, out_pos, tb)
+    return PlanStats(n=bmmc.n, t=t, n_over=n_over, n_tiles=1 << len(tb),
+                     rows_per_tile=1 << len(row_pos), row_len=1 << t,
                      in_run=1 << k_in, out_run=1 << k_out)
 
 
 def plan_stats_general(bmmc: Bmmc, t: int) -> Optional[PlanStats]:
     """Analytic counterpart of :func:`plan_general` (O(n^2) bit math)."""
-    n = bmmc.n
-    if not 0 < t <= n:
+    lay = _general_layout(bmmc, t)
+    if lay is None:
         return None
-    a, row_dirs = _split_directions(bmmc, t)
-    if n - 2 * t + a < 0:
-        return None
-    tb = _tb_complement(row_dirs, t, n)
-    rpt = 1 << (t - a)
-    chosen_low = _out_low_positions(bmmc, t, t - a)
-
+    a, row_dirs, out_pos, tb = lay
     # input-run: in_rows[g, r] counts binarily in r iff high(row_dirs[i])
     # == 2^i for i < k and nothing else (higher dirs, tb base bits)
     # touches the low k row-id bits.
@@ -532,22 +670,9 @@ def plan_stats_general(bmmc: Bmmc, t: int) -> Optional[PlanStats]:
         if not ok:
             break
         k_in = k
-
-    deltas = [f2.matvec(bmmc.rows, 1 << pos) >> t for pos in chosen_low]
-    others = [f2.matvec(bmmc.rows, 1 << pos) >> t for pos in tb]
-    others.append(bmmc.c >> t)
-    k_out = 0
-    while k_out < len(deltas):
-        k = k_out + 1
-        mask = (1 << k) - 1
-        ok = all(deltas[i] == (1 << i) for i in range(k))
-        ok = ok and all((d & mask) == 0 for d in deltas[k:])
-        ok = ok and all((o & mask) == 0 for o in others)
-        if not ok:
-            break
-        k_out = k
-    return PlanStats(n=n, t=t, n_over=a, n_tiles=1 << len(tb),
-                     rows_per_tile=rpt, row_len=1 << t,
+    k_out = _out_run_bits(bmmc, t, out_pos, tb)
+    return PlanStats(n=bmmc.n, t=t, n_over=a, n_tiles=1 << len(tb),
+                     rows_per_tile=1 << len(row_dirs), row_len=1 << t,
                      in_run=1 << k_in, out_run=1 << k_out)
 
 
@@ -709,8 +834,10 @@ def _block_granularity(bmmc: Bmmc) -> int:
 
 
 def _lane_rows_per_block(n: int, t: int) -> int:
-    """Rows staged per grid step: one copy-sized block when available."""
-    return max(1, min(1 << (n - t), 1 << max(0, _COPY_BLOCK_BITS - t)))
+    """Rows staged per grid step: a square 2^t x 2^t block (the tiled
+    pass's VMEM budget), so the one-hot lane matrix is built once per
+    2^t rows; all rows when the array has fewer."""
+    return min(1 << (n - t), 1 << t)
 
 
 def plan_block(bmmc: Bmmc, t: int) -> Optional[BlockPlan]:

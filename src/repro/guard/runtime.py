@@ -57,11 +57,12 @@ def resolve_flags(mask: int) -> tuple:
     return tuple(k for k, bit in sorted(TRAP_KINDS.items()) if mask & bit)
 
 
-def _trace_state_clean() -> bool:
-    try:
-        return jax.core.trace_state_clean()
-    except AttributeError:  # pragma: no cover - older/newer jax
-        return True
+def _trace_state_clean(x) -> bool:
+    """False when a leaf of ``x`` is a tracer, i.e. the call sits inside
+    an outer ``jit``/``grad``/``vmap`` trace where no flag can be read
+    back."""
+    return not any(isinstance(leaf, jax.core.Tracer)
+                   for leaf in jax.tree.leaves(x))
 
 
 # ---------------------------------------------------------------------------
@@ -272,7 +273,7 @@ def _observed_guarded_call(prog: tuple, t, x, engine: str, batched: bool):
 
 @functools.lru_cache(maxsize=256)
 def _guarded_permute_executable(rows: tuple, c: int, t, engine: str,
-                                interpret: bool, batched: bool):
+                                batched: bool):
     """Guarded twin of :func:`repro.kernels.ops.bmmc_permute` for one
     BMMC: kernel dispatch + probes in one jit."""
     from ..combinators.ir import Perm
@@ -282,8 +283,7 @@ def _guarded_permute_executable(rows: tuple, c: int, t, engine: str,
     probe = _build_probe((Perm(bmmc),), t, engine, batched)
 
     def run(x):
-        y = ops.bmmc_permute(x, bmmc, t=t, engine=engine,
-                             interpret=interpret, batched=batched)
+        y = ops.bmmc_permute(x, bmmc, t=t, engine=engine, batched=batched)
         return y, probe(x, y)
 
     return jax.jit(run)
@@ -370,8 +370,7 @@ def guarded_call(prog, t, x, engine, batched: bool):
     return _resolve_or_fallback(prog, t, x, engine, batched, run_engine)
 
 
-def guarded_bmmc_permute(x, bmmc: Bmmc, *, t, engine: str, interpret: bool,
-                         batched: bool):
+def guarded_bmmc_permute(x, bmmc: Bmmc, *, t, engine: str, batched: bool):
     """Guarded :func:`repro.kernels.ops.bmmc_permute`: verify + dispatch
     validation, probes in-program, pallas → ref fallback."""
     from ..kernels import ops
@@ -387,7 +386,7 @@ def guarded_bmmc_permute(x, bmmc: Bmmc, *, t, engine: str, interpret: bool,
 
     def run_engine(eng):
         return _guarded_permute_executable(bmmc.rows, bmmc.c, t, eng,
-                                           interpret, batched)
+                                           batched)
 
     from ..combinators.ir import Perm
     return _resolve_or_fallback((Perm(bmmc),), teff, x, engine, batched,

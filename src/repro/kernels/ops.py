@@ -33,7 +33,8 @@ from ..core.tiling import (class_stats, copy_descriptors, dispatch_kernel,
 from ..obs import metrics as _ometrics
 from ..obs import trace as _otrace
 from . import ref as _ref
-from .bmmc_permute import block_permute, lane_permute, tiled_permute
+from .bmmc_permute import (block_permute, check_lanes, lane_permute,
+                           tiled_permute)
 
 # VMEM working-set budget for one tile buffer. The double-buffered pipeline
 # holds 2 * num_buffers tile-sized slots (in + out, default num_buffers=2);
@@ -123,6 +124,8 @@ def class_dispatch(x: jax.Array, bmmc: Bmmc, t: Optional[int],
     d = x.shape[1 + lead] if x.ndim == 2 + lead else 1
     teff = choose_tile(bmmc.n, x.dtype.itemsize, d, t)
     if teff is None:
+        # the interpreter takes the gather oracle; the chip refuses
+        check_lanes(d)
         return None
     if not _otrace._state.enabled:
         return class_plan(bmmc, teff)
@@ -138,8 +141,7 @@ def class_dispatch(x: jax.Array, bmmc: Bmmc, t: Optional[int],
 
 
 def bmmc_permute(x: jax.Array, bmmc: Bmmc, *, t: Optional[int] = None,
-                 engine: str = "pallas", interpret: bool = True,
-                 batched: bool = False) -> jax.Array:
+                 engine: str = "pallas", batched: bool = False) -> jax.Array:
     """Permute ``x`` (shape (2^n,) or (2^n, d)) by ``out[A i ^ c] = x[i]``.
 
     ``engine``: "pallas" (class-dispatched kernels) or "ref" (pure-jnp
@@ -151,13 +153,12 @@ def bmmc_permute(x: jax.Array, bmmc: Bmmc, *, t: Optional[int] = None,
     from .. import guard as _guard
     if _guard.enabled() and engine in ("pallas", "ref"):
         from ..guard import runtime as _grt
-        if _grt._trace_state_clean():
+        if _grt._trace_state_clean(x):
             # ring 2: guarded twin — kernel + probes in one executable,
             # flag readback + pallas → ref fallback at this edge. Under
             # an outer trace the readback is impossible; fall through.
             return _grt.guarded_bmmc_permute(
-                x, bmmc, t=t, engine=engine, interpret=interpret,
-                batched=batched)
+                x, bmmc, t=t, engine=engine, batched=batched)
     if engine == "ref":
         return _ref.bmmc_ref(x, bmmc, batched=batched)
     if bmmc.is_identity_perm():
@@ -168,13 +169,11 @@ def bmmc_permute(x: jax.Array, bmmc: Bmmc, *, t: Optional[int] = None,
         return _ref.bmmc_ref(x, bmmc, batched=batched)
     kernel, payload = got
     if kernel == "block":
-        return block_permute(x, payload, interpret=interpret,
-                             batched=batched)
+        return block_permute(x, payload, batched=batched)
     if kernel == "lane":
-        return lane_permute(x, payload, interpret=interpret,
-                            batched=batched)
+        return lane_permute(x, payload, batched=batched)
     for plan in payload:
-        x = tiled_permute(x, plan, interpret=interpret, batched=batched)
+        x = tiled_permute(x, plan, batched=batched)
     return x
 
 
@@ -185,18 +184,19 @@ def num_passes(bmmc: Bmmc, t: int) -> int:
 
 
 def make_bmmc_permute(bmmc: Bmmc, *, t: Optional[int] = None,
-                      engine: str = "pallas", interpret: bool = True):
+                      engine: str = "pallas"):
     """Returns a jit-compiled unary function specialized to ``bmmc``."""
     @jax.jit
     def fn(x):
-        return bmmc_permute(x, bmmc, t=t, engine=engine, interpret=interpret)
+        return bmmc_permute(x, bmmc, t=t, engine=engine)
     return fn
 
 
 # ---------------------------------------------------------------------------
 # Transaction model — the offline counterpart of the paper's effective-
-# bandwidth measurements (used by the benchmark harness; no GPU/TPU clock
-# exists in this container, see DESIGN.md §7.4).
+# bandwidth measurements: descriptor and byte counts derived from the
+# plans, valid on any backend. Times come only from runs on the chip
+# (DESIGN.md §7.4).
 # ---------------------------------------------------------------------------
 
 def modeled_transactions(bmmc: Bmmc, t: int, itemsize: int = 4) -> dict:

@@ -51,6 +51,7 @@ from .. import guard, obs, resilience, store as _store
 from ..configs import get_config, reduce_for_smoke
 from ..models import model as M
 from ..models.layers import init_params
+from .compile_cache import setup_compile_cache
 
 
 def _guard_resolve(where: str, base: dict) -> dict:
@@ -132,6 +133,7 @@ def main(argv=None):
                          "reduce to 2 kv heads, whose 1-bit shuffle is "
                          "identity, so --head-shuffle demos want >= 4")
     args = ap.parse_args(argv)
+    setup_compile_cache()
     if args.telemetry or args.trace:
         obs.enable(sync=True)
     if args.validate:

@@ -29,6 +29,7 @@ from ..data.pipeline import DataConfig, ShardedLoader
 from ..models import model as M
 from ..optim.schedule import warmup_cosine
 from ..train.step import init_opt, make_train_step
+from .compile_cache import setup_compile_cache
 
 PROFILES = {
     # name -> (d_model, layers, heads, d_ff, vocab)  [~params]
@@ -63,6 +64,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     if args.arch:
         cfg = reduce_for_smoke(get_config(args.arch))

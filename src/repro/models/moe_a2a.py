@@ -59,10 +59,7 @@ def _device_moe(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     """Per-device body. x: (T_local, E). Expert weights arrive model-sharded
     on dim 0 and FSDP-sharded over dp on the embed dim; gathered here."""
     t, e = x.shape
-    if hasattr(jax.lax, "axis_size"):
-        n_peers = jax.lax.axis_size(model_axis)
-    else:  # jax < 0.5: psum of a python literal folds to the static size
-        n_peers = int(jax.lax.psum(1, model_axis))
+    n_peers = jax.lax.axis_size(model_axis)
     xpp = n_experts // n_peers                     # experts per peer
 
     def gather_dp(w, axis):
@@ -166,7 +163,6 @@ def moe_ffn_a2a(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     ``dispatch_shuffle`` BMMC-permutes send slots within each peer block
     (neutral at equal capacity; rounds capacity to a power of two — see
     module docstring)."""
-    from jax.experimental.shard_map import shard_map
     from ..parallel.sharding import dp_axes as _dp
     dp = _dp(mesh)
     dp_entry = dp if len(dp) > 1 else dp[0]
@@ -183,7 +179,7 @@ def moe_ffn_a2a(x, router_w, w_gate, w_up, w_down, *, top_k: int,
         return out.reshape(b, s, e), aux
 
     emb_spec = dp_entry  # FSDP axis for the embed dim of expert weights
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(dp_entry, "model", None),       # x
                   P(None, None),                     # router (replicated)
@@ -191,5 +187,5 @@ def moe_ffn_a2a(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                   P("model", emb_spec, None),        # w_up
                   P("model", None, emb_spec)),       # w_down (X, F, E)
         out_specs=(P(dp_entry, "model", None), P()),
-        check_rep=False)
+        check_vma=False)
     return mapped(x, router_w, w_gate, w_up, w_down)

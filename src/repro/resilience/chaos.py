@@ -311,7 +311,6 @@ def sigterm_drill(tokens: int = 6000, timeout_s: float = 240.0) -> dict:
     decoding has started, and verify the graceful-drain contract: exit
     code 0, a ``drained:`` marker, the complete summary (decode report
     + guard resolution), and no traceback."""
-    import os
     import signal
     import subprocess
     import sys
@@ -321,10 +320,8 @@ def sigterm_drill(tokens: int = 6000, timeout_s: float = 240.0) -> dict:
            "--arch", "mistral-nemo-12b", "--batch", "2",
            "--prompt-len", "8", "--tokens", str(tokens),
            "--validate", "--error-budget", "0"]
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
-                            stderr=subprocess.STDOUT, text=True, env=env)
+                            stderr=subprocess.STDOUT, text=True)
     out_lines = []
     started = False
     deadline = time.monotonic() + timeout_s

@@ -37,11 +37,11 @@ from ..models import model as M
 from ..optim.adamw import AdamWConfig, adamw_init, adamw_update, state_shapes
 
 
-def _trace_state_clean() -> bool:
-    try:
-        return jax.core.trace_state_clean()
-    except AttributeError:  # pragma: no cover - older/newer jax
-        return True
+def _trace_state_clean(tree) -> bool:
+    """False when a leaf of ``tree`` is a tracer (the step is being
+    traced by an outer ``jit``, not executed)."""
+    return not any(isinstance(leaf, jax.core.Tracer)
+                   for leaf in jax.tree.leaves(tree))
 
 
 def _instrument_step(train_step: Callable) -> Callable:
@@ -51,7 +51,7 @@ def _instrument_step(train_step: Callable) -> Callable:
 
     @functools.wraps(train_step)
     def observed(params, opt_state, batch):
-        if not (obs.enabled() and _trace_state_clean()):
+        if not (obs.enabled() and _trace_state_clean(batch)):
             return train_step(params, opt_state, batch)
         rt0 = obs.counter_total("model.round_trips")
         vjp0 = obs.counter_total("model.vjp_round_trips")
@@ -117,7 +117,7 @@ def _guard_step(train_step: Callable, trap_retries: int = 1) -> Callable:
                     raise
                 attempt += 1
                 _rp._record("retries", obs_name="resilience.retry")
-        if not _trace_state_clean():
+        if not _trace_state_clean(out):
             return out
         metrics = out[2]
         bad = [k for k in ("loss", "grad_norm")

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.combinators import cache_stats, clear_caches, compile_expr
 from repro.combinators import vocab as V

@@ -3,7 +3,7 @@ import random
 
 import numpy as np
 import pytest
-from _hyp_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import f2
 from repro.core.bmmc import Bmmc

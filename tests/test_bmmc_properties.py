@@ -1,4 +1,4 @@
-"""Property-based F2/BMMC algebra tests (via the _hyp_compat shim).
+"""Property-based F2/BMMC algebra tests (hypothesis).
 
 Random compose/inverse round-trips, ``f2.ulp`` factorization validity and
 BP/BPC/tiled classification invariants across sizes n = 2..16 — the
@@ -7,7 +7,7 @@ offline algebra every kernel plan and every autodiff inverse relies on.
 import random
 
 import pytest
-from _hyp_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import f2
 from repro.core.bmmc import Bmmc

@@ -4,7 +4,7 @@ import random
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.combinators import (compile_expr, fuse, lower, num_perm_stages,
                                run_program, vocab as V)

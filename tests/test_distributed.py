@@ -8,7 +8,7 @@ import subprocess
 import sys
 
 import pytest
-from _hyp_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.bmmc import Bmmc
 from repro.core.distributed import make_plan, plan_cost, plan_to_bmmc

@@ -2,7 +2,7 @@
 import random
 
 import pytest
-from _hyp_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import f2
 

@@ -17,9 +17,8 @@ import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch.hlo_analysis import analyze_hlo
 
-kw = ({"axis_types": (jax.sharding.AxisType.Auto,) * 2}
-      if hasattr(jax.sharding, "AxisType") else {})
-mesh = jax.make_mesh((4, 2), ("data", "model"), **kw)
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 M, N, K, T = 256, 128, 64, 5
 
 def f(x, w):

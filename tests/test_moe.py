@@ -103,9 +103,8 @@ A2A_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-kw = ({"axis_types": (jax.sharding.AxisType.Auto,)*2}
-      if hasattr(jax.sharding, "AxisType") else {})
-mesh = jax.make_mesh((2, 4), ("data", "model"), **kw)
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 from repro.models.moe_a2a import moe_ffn_a2a
 
 B, S, E, F, X, K = 2, 16, 8, 12, 8, 2

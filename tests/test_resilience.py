@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hyp_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from repro import guard, resilience
 from repro.resilience import breaker, chaos, policy
 
@@ -400,7 +400,9 @@ def test_serve_tokens_1_reports_na_throughput(capsys):
 
 
 @pytest.mark.slow
-def test_serve_sigterm_drains_gracefully():
+def test_serve_sigterm_drains_gracefully(monkeypatch):
+    # the drill's serve child inherits this environment; pin it to the CPU
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     drill = chaos.sigterm_drill()
     assert drill["started"], drill["output"][-2000:]
     assert drill["ok"], drill["output"][-2000:]

@@ -3,10 +3,11 @@ import random
 
 import numpy as np
 import pytest
-from _hyp_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.bmmc import Bmmc
-from repro.core.tiling import naive_write_runs, plan_bmmc, plan_tiled
+from repro.core.tiling import (naive_write_runs, plan_bmmc, plan_general,
+                               plan_tiled)
 
 
 @given(st.integers(6, 12), st.integers(0, 10**6), st.integers(2, 4))
@@ -66,6 +67,78 @@ def test_simulated_kernel_matches_reference():
             for i in range(1 << n):
                 want[b.apply(i)] = x[i]
             assert np.array_equal(cur, want)
+
+
+def _parity(v):
+    v = v.copy()
+    for sh in (16, 8, 4, 2, 1):
+        v ^= v >> sh
+    return v & 1
+
+
+def _apply_intra(im, tile, g):
+    """The kernel's intra-tile steps (``IntraMap``) in numpy: shears as
+    XOR moves, the row and lane maps as one-hot matmuls."""
+    rows, cols = tile.shape
+    p, t = rows.bit_length() - 1, cols.bit_length() - 1
+    w = [int(v) for v in im.words]
+    f1, sinv, dcol = w[:p], w[p:2 * p], w[2 * p:2 * p + t]
+    e, f0 = w[2 * p + t:2 * p + 2 * t], w[2 * p + 2 * t:]
+    r, c = np.arange(rows)[:, None], np.arange(cols)[None, :]
+    k = int(im.ktab[g])
+
+    def row_shear(x, f):
+        for j in range(p):
+            x = np.where(_parity(c & f[j]) == 1, x[r ^ (1 << j), c], x)
+        return x
+
+    def span(idx, basis):
+        out = np.zeros_like(idx)
+        for i, v in enumerate(basis):
+            out ^= ((idx >> i) & 1) * v
+        return out
+
+    x = tile
+    if im.steps[0]:
+        x = row_shear(x, f1)
+    if im.steps[1]:
+        src = span(np.arange(rows), sinv) ^ (k >> t)
+        x = (np.arange(rows)[:, None] == src[None, :]).astype(np.int64) @ x
+    if im.steps[2]:
+        src = span(np.arange(cols), dcol) ^ (k & (cols - 1))
+        x = x @ (np.arange(cols)[:, None] == src[None, :]).astype(np.int64)
+    if im.steps[3]:
+        for i in range(t):
+            x = np.where(_parity(r & e[i]) == 1, x[r, c ^ (1 << i)], x)
+    if im.steps[4]:
+        x = row_shear(x, f0)
+    return x
+
+
+@pytest.mark.parametrize("n,t", [(8, 4), (10, 3), (12, 4), (9, 4)])
+def test_intra_factorization_matches_src0_gather(n, t):
+    """The factored intra-tile steps reproduce every tile's ``src0`` /
+    ``xor_low`` gather, and the inverse factorization undoes it; general
+    plans include BMMCs with a nonzero complement."""
+    rng = random.Random(n * 100 + t)
+    cases = [Bmmc.bit_reverse(n), Bmmc.random_bpc(n, rng), Bmmc.random(n, rng)]
+    cases.append(Bmmc(cases[-1].rows, rng.getrandbits(n) | 1))
+    for b in cases:
+        plans = list(plan_bmmc(b, t))
+        general = plan_general(b, t)
+        if general is not None:
+            plans.append(general)
+        for p in plans:
+            rows, cols = p.rows_per_tile, p.row_len
+            tile = np.arange(rows * cols).reshape(rows, cols)
+            j = np.arange(rows * cols)
+            for g in range(p.n_tiles):
+                src = p.src0.reshape(-1)[(j & ~(cols - 1))
+                                         | ((j ^ p.xor_low[g]) & (cols - 1))]
+                want = tile.reshape(-1)[src].reshape(rows, cols)
+                assert np.array_equal(_apply_intra(p.intra, tile, g), want)
+                assert np.array_equal(_apply_intra(p.intra_inv, want, g),
+                                      tile)
 
 
 def test_transaction_model_tiled_vs_naive():

@@ -1,0 +1,153 @@
+"""Compile-only rehearsals: every kernel class compiled for a described
+TPU v5e at real sizes (2^24 int32 for the permutation suite, the 2^20
+sort's fused compare cluster, a fused planar FFT butterfly cluster and
+the gradient kernel). Nothing runs — the chip's compiler only has to
+accept each kernel, which the Pallas interpreter cannot show.
+
+The topology is described inside a module fixture (never at import:
+only one process may load the TPU library), and the test steers the
+package's single backend decision to the compiled path itself.
+"""
+import functools
+import random
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.combinators import execute as ex
+from repro.combinators.fft import compiled_fft
+from repro.combinators.ir import Bfly, CmpHalves
+from repro.combinators.optimize import FusedStage
+from repro.combinators.sort import compiled_sort
+from repro.core.bmmc import Bmmc
+from repro.core.tiling import plan_block, plan_bmmc, plan_lane
+from repro.kernels import bmmc_permute as bp
+from repro.kernels.ops import choose_tile
+
+N = 24
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a chip compile written to the persistent cache cannot be read back
+    # without the chip; keep these compiles out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def chip(monkeypatch, one_chip):
+    """Compile ``fn`` at the given shapes for one described v5e chip and
+    return the HLO text; the kernels take their compiled (not
+    interpreted) path for the duration of the test."""
+    monkeypatch.setattr(bp, "interpret_mode", lambda: False)
+
+    def compile_text(fn, *shapes):
+        specs = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                 for s, d in shapes]
+        return jax.jit(fn).lower(*specs).compile().as_text()
+    return compile_text
+
+
+def _has_kernel(text: str) -> bool:
+    return "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("name", ["bit_reverse", "bmmc"])
+def test_tiled_pass_compiles(chip, name):
+    b = (Bmmc.bit_reverse(N) if name == "bit_reverse"
+         else Bmmc.random(N, random.Random(7)))
+    t = choose_tile(N, 4)
+    (plan,) = plan_bmmc(b, t)
+    fn = functools.partial(bp.tiled_permute_tables,
+                           geometry=bp.plan_geometry(plan))
+    tables = bp.plan_tables(plan)
+    assert _has_kernel(chip(lambda x: fn(x, *tables), ((1 << N,), jnp.int32)))
+
+
+def test_block_and_lane_compile(chip):
+    t = choose_tile(N, 4)
+    perm = list(range(N))     # swap two high bits: whole blocks move
+    perm[N - 1], perm[t + 2] = perm[t + 2], perm[N - 1]
+    block = Bmmc.from_perm(perm)
+    bplan = plan_block(block, t)
+    assert bplan is not None
+    lane = Bmmc.from_perm(list(reversed(range(t))) + list(range(t, N)))
+    lplan = plan_lane(lane, t)
+    assert lplan is not None
+    x = ((1 << N,), jnp.int32)
+    assert _has_kernel(chip(lambda v: bp.block_permute_tables(
+        v, bplan.src_rows, geometry=bp.block_geometry(bplan)), x))
+    assert _has_kernel(chip(lambda v: bp.lane_permute_tables(
+        v, lplan.src_lane, geometry=bp.lane_geometry(lplan)), x))
+    assert _has_kernel(chip(bp.copy_through_vmem, x))
+
+
+def _cluster(prog, kind, want: int):
+    """The first cluster carrying ``want`` computes of ``kind``."""
+    for s in prog:
+        if isinstance(s, FusedStage) and sum(
+                isinstance(c, kind) for c, _ in s.computes) >= want:
+            return s
+    raise AssertionError("no such cluster")
+
+
+def _fused_pass(fs, t, dtype, inverse=False):
+    plans, entries = ex._fused_plan_cached(fs, t)
+    sig, scal, vmem, map_fns = ex._fused_kernel_args(entries, dtype)
+    geom = bp.plan_geometry(plans[0], inverse=inverse)
+    tables = bp.plan_tables(plans[0], inverse=inverse)
+    kern = bp.tiled_permute_bwd_tables if inverse else bp.tiled_permute_tables
+    fn = functools.partial(kern, geometry=geom, epilogue=sig,
+                           map_fns=map_fns)
+    return fn, tables, scal, vmem
+
+
+def test_fused_sort_cmp_cluster_compiles(chip):
+    n = 20
+    t = choose_tile(n, 4)
+    fs = _cluster(compiled_sort(n, engine="pallas").clustered_program(n, t),
+                  CmpHalves, 3)
+    fn, tables, scal, vmem = _fused_pass(fs, t, jnp.float32)
+    text = chip(lambda x: fn(x, *tables, epi_scalar=scal, epi_vmem=vmem),
+                ((1 << n,), jnp.float32))
+    assert _has_kernel(text)
+
+
+def test_fused_fft_bfly_cluster_compiles(chip):
+    n = 16
+    t = choose_tile(n, 4, 2)
+    fs = _cluster(compiled_fft(n, engine="pallas").clustered_program(n, t),
+                  Bfly, 2)
+    fn, tables, scal, vmem = _fused_pass(fs, t, jnp.float32)
+    text = chip(lambda x: fn(x, *tables, epi_scalar=scal, epi_vmem=vmem),
+                ((1 << n, 2), jnp.float32))
+    assert _has_kernel(text)
+
+
+def test_gradient_kernel_with_cmp_compiles(chip):
+    n = 20
+    t = choose_tile(n, 4)
+    fs = _cluster(compiled_sort(n, engine="pallas").clustered_program(n, t),
+                  CmpHalves, 3)
+    fn, tables, scal, vmem = _fused_pass(fs, t, jnp.float32, inverse=True)
+    x = ((1 << n,), jnp.float32)
+    text = chip(lambda v, c: fn(v, c, *tables, epi_scalar=scal,
+                                epi_vmem=vmem), x, x)
+    assert _has_kernel(text)
