@@ -258,8 +258,11 @@ def _fused_plan_cached(fs: FusedStage, t: int):
     re-audited through guard ring 1 before it is trusted."""
     from .. import store as _store
 
-    return _store.fused_plan_through(
-        fs, t, lambda: _build_fused_plan(fs, t))
+    def build():
+        with _otrace.span("plan.fused", stages=len(fs.stages), t=t):
+            return _build_fused_plan(fs, t)
+
+    return _store.fused_plan_through(fs, t, build)
 
 
 @functools.lru_cache(maxsize=64)
@@ -552,6 +555,11 @@ def _program_bwd_plan(prog: Program, batched: bool):
       :class:`FusedStage` (one megakernel/class-dispatch pass), or None
       if it collapses to the identity.
     """
+    with _otrace.span("plan.bwd", stages=len(prog)):
+        return _build_bwd_plan(prog, batched)
+
+
+def _build_bwd_plan(prog: Program, batched: bool):
     n = None
     for st in prog:
         if isinstance(st, FusedStage):
@@ -1051,15 +1059,17 @@ def run_program(program: Sequence[Expr], x: jax.Array,
 
 @functools.lru_cache(maxsize=1024)
 def _lowered_cached(expr: Expr, n: int, optimized: bool) -> Program:
-    prog = lower(expr, n)
-    return fuse(prog) if optimized else prog
+    with _otrace.span("plan.lower", n=n):
+        prog = lower(expr, n)
+        return fuse(prog) if optimized else prog
 
 
 @functools.lru_cache(maxsize=1024)
 def _clustered_cached(expr: Expr, n: int, optimized: bool,
                       t: int) -> tuple:
-    prog = cluster(_lowered_cached(expr, n, optimized), n, t)
-    return fold_free(prog, n, t)
+    prog = _lowered_cached(expr, n, optimized)
+    with _otrace.span("plan.cluster", n=n, t=t):
+        return fold_free(cluster(prog, n, t), n, t)
 
 
 # ---------------------------------------------------------------------------
@@ -1093,31 +1103,20 @@ def _program_executable(prog: Program, engine: str, batched: bool):
 
 
 @functools.lru_cache(maxsize=512)
-def _program_round_trips(prog: Program, t: Optional[int]) -> Optional[int]:
-    """Modeled HBM round trips of a resolved program — the per-call
-    model-vs-measured accounting unit (telemetry only)."""
-    if t is None:
-        return None
-    from .optimize import program_cost
-    return program_cost(prog, t)["round_trips"]
-
-
-@functools.lru_cache(maxsize=512)
 def _inverse_program_cached(prog: Program) -> Program:
     """The offline-inverted program (clusters invert to clusters) —
     what :func:`program_apply`'s backward dispatches."""
     return inverse_program(prog)
 
 
-def _observed_program_call(prog: Program, t: Optional[int], x: jax.Array,
-                           engine, batched: bool,
-                           use_exec: bool) -> jax.Array:
+def _observed_program_call(prog: Program, x: jax.Array, engine,
+                           batched: bool, use_exec: bool) -> jax.Array:
     """The telemetry-enabled whole-program call path: one
     ``program.call`` span + latency histogram per invocation, warm/cold
-    labeled by whether a fresh jit trace ran, and the modeled round
-    trips accumulated so ``obs.model_vs_measured()`` can hold the
-    transaction model against the wall clock. Blocks on the result only
-    when ``obs.enable(sync=True)`` asked for end-to-end timings."""
+    labeled by whether a fresh jit trace ran. Inside it, the executable's
+    cache lookup (``program.lookup``) is timed apart from its call up to
+    its return (``program.enqueue``). Blocks on the result only when
+    ``obs.enable(sync=True)`` asked for end-to-end timings."""
     eng = engine if isinstance(engine, str) else "injected"
     with _otrace.span("program.call", engine=eng, stages=len(prog),
                       path="executable" if use_exec else "per-stage",
@@ -1125,31 +1124,26 @@ def _observed_program_call(prog: Program, t: Optional[int], x: jax.Array,
         t0 = time.perf_counter_ns()
         if use_exec:
             misses0 = _program_executable.cache_info().misses
-            out = _program_executable(prog, engine, batched)(x)
+            with _otrace.span("program.lookup"):
+                run = _program_executable(prog, engine, batched)
             cold = _program_executable.cache_info().misses > misses0
+            with _otrace.span("program.enqueue"):
+                out = run(x)
         else:
             out = run_program(prog, x, engine, batched=batched)
             cold = False
         if _otrace._state.sync:
             jax.block_until_ready(out)
         dur_us = (time.perf_counter_ns() - t0) / 1e3
-        rt = _program_round_trips(prog, t)
         sargs["dur_us"] = round(dur_us, 1)
         sargs["cache"] = "cold" if cold else "warm"
-        if rt is not None:
-            sargs["model_round_trips"] = rt
     _ometrics.observe("program.call_us", dur_us, engine=eng,
                       cache="cold" if cold else "warm")
-    if rt is not None:
-        _ometrics.inc("program.model_round_trips", rt)
-        if not cold:
-            _ometrics.observe("program.us_per_round_trip",
-                              dur_us / max(rt, 1), engine=eng)
     return out
 
 
-def _dispatch_program(prog: Program, t: Optional[int], x: jax.Array,
-                      engine, batched: bool) -> jax.Array:
+def _dispatch_program(prog: Program, x: jax.Array, engine,
+                      batched: bool) -> jax.Array:
     """Run a resolved program: whole-program executable when the engine
     is named and the program carries no user ``Map`` (one XLA dispatch
     per call), eager per-stage otherwise; observed when telemetry is on."""
@@ -1158,7 +1152,7 @@ def _dispatch_program(prog: Program, t: Optional[int], x: jax.Array,
         if use_exec:
             return _program_executable(prog, engine, batched)(x)
         return run_program(prog, x, engine, batched=batched)
-    return _observed_program_call(prog, t, x, engine, batched, use_exec)
+    return _observed_program_call(prog, x, engine, batched, use_exec)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
@@ -1183,7 +1177,7 @@ def program_apply(x: jax.Array, prog: Program, t: Optional[int],
     - anything else (``Map`` stages, complex dtypes, non-planar
       butterflies) falls back to the per-stage ``jax.vjp`` replay.
     """
-    return _dispatch_program(prog, t, x, engine, batched)
+    return _dispatch_program(prog, x, engine, batched)
 
 
 def _program_apply_fwd(x, prog, t, engine, batched):
@@ -1232,7 +1226,7 @@ def cache_stats() -> Dict[str, CacheStats]:
 
     Covers the kernel-executable caches (``geom`` / ``block`` / ``lane``
     / ``program``), the plan/table caches (``fused_plan`` / ``w_planar``
-    / ``lowered`` / ``clustered`` / ``model_round_trips`` and the ops
+    / ``lowered`` / ``clustered`` and the ops
     ``plans`` / ``class_plan``), and the ``compiled_exprs`` memo.
     Replaces the old single-cache ``geom_cache_info`` /
     ``program_cache_info`` pair, which made every other cache invisible.
@@ -1248,7 +1242,6 @@ def cache_stats() -> Dict[str, CacheStats]:
         "w_planar": _w_planar_cached,
         "lowered": _lowered_cached,
         "clustered": _clustered_cached,
-        "model_round_trips": _program_round_trips,
         "inverse_program": _inverse_program_cached,
         "fused_inverse": _fused_inverse_cached,
         "program_bwd_plan": _program_bwd_plan,
@@ -1385,7 +1378,21 @@ class CompiledExpr:
         return self._resolve(x, batched)[0]
 
     def __call__(self, x: jax.Array, *, batched: bool = False) -> jax.Array:
-        prog, t = self._resolve(x, batched)
+        if not _otrace._state.enabled:
+            prog, t = self._resolve(x, batched)
+            run, args = self._route(prog, t, x, batched)
+            return run(*args)
+        with _otrace.span("entry.resolve"):
+            prog, t = self._resolve(x, batched)
+        with _otrace.span("entry.route"):
+            run, args = self._route(prog, t, x, batched)
+        with _otrace.span("entry.apply"):
+            return run(*args)
+
+    def _route(self, prog: Program, t: Optional[int], x: jax.Array,
+               batched: bool) -> tuple:
+        """The dispatch that runs the resolved program on ``x``: a
+        function and the arguments to call it with."""
         from .. import guard as _g
         if _g.enabled():
             from ..guard import runtime as _grt
@@ -1397,7 +1404,7 @@ class CompiledExpr:
                 # ref fallback machine on a trap. Skipped under an
                 # outer trace (the flag readback needs a concrete
                 # value); ring 1 in _resolve still ran.
-                return _grt.guarded_call(prog, t, x, self.engine, batched)
+                return _grt.guarded_call, (prog, t, x, self.engine, batched)
         # Programs carrying user Map callables stay on the eager
         # per-stage path (inside _dispatch_program): Map's contract says
         # "a jax function", but eager execution historically tolerated
@@ -1412,15 +1419,15 @@ class CompiledExpr:
             if isinstance(self.engine, str):
                 _program_executable(_inverse_program_cached(prog),
                                     self.engine, batched)
-            return program_apply(x, prog, t, self.engine, batched)
+            return program_apply, (x, prog, t, self.engine, batched)
         if (not _has_map(prog)
                 and _program_bwd_plan(prog, batched) is not None):
             # compute-bearing program with a collapsed backward plan:
             # one custom-vjp boundary; the backward sweeps every
             # transposed pairwise compute in forward-output coordinates
             # and finishes with ONE composed inverse BMMC pass (§13)
-            return program_apply(x, prog, t, self.engine, batched)
-        return _dispatch_program(prog, t, x, self.engine, batched)
+            return program_apply, (x, prog, t, self.engine, batched)
+        return _dispatch_program, (prog, x, self.engine, batched)
 
     def call_per_stage(self, x: jax.Array, *,
                        batched: bool = False) -> jax.Array:
@@ -1459,7 +1466,6 @@ def clear_caches() -> None:
     _w_planar_cached.cache_clear()
     _lowered_cached.cache_clear()
     _clustered_cached.cache_clear()
-    _program_round_trips.cache_clear()
     _inverse_program_cached.cache_clear()
     _fused_inverse_cached.cache_clear()
     _program_bwd_plan.cache_clear()

@@ -242,9 +242,7 @@ def _observed_guarded_call(prog: tuple, t, x, engine: str, batched: bool):
     :func:`~repro.combinators.execute._observed_program_call` for the
     guarded executable: one ``program.call`` span + latency histogram
     per invocation (the executor sites inside fire at trace time only),
-    cold/warm labeled by the guarded cache, modeled round trips
-    accumulated for ``obs.model_vs_measured()``."""
-    from ..combinators import execute as _ex
+    cold/warm labeled by the guarded cache."""
     from ..obs import metrics as _ometrics, trace as _otrace
 
     with _otrace.span("program.call", engine=engine, stages=len(prog),
@@ -256,18 +254,10 @@ def _observed_guarded_call(prog: tuple, t, x, engine: str, batched: bool):
         if _otrace._state.sync:
             jax.block_until_ready(y)
         dur_us = (time.perf_counter_ns() - t0) / 1e3
-        rt = _ex._program_round_trips(prog, t)
         sargs["dur_us"] = round(dur_us, 1)
         sargs["cache"] = "cold" if cold else "warm"
-        if rt is not None:
-            sargs["model_round_trips"] = rt
     _ometrics.observe("program.call_us", dur_us, engine=engine,
                       cache="cold" if cold else "warm")
-    if rt is not None:
-        _ometrics.inc("program.model_round_trips", rt)
-        if not cold:
-            _ometrics.observe("program.us_per_round_trip",
-                              dur_us / max(rt, 1), engine=engine)
     return y, flags
 
 
@@ -359,7 +349,7 @@ def guarded_call(prog, t, x, engine, batched: bool):
         # injected engines and user-Map programs stay on the eager
         # unguarded dispatch path (jitting an unknown callable would
         # break the Map contract's trace-tolerance); ring 1 still ran
-        return _ex._dispatch_program(prog, t, x, engine, batched)
+        return _ex._dispatch_program(prog, x, engine, batched)
 
     def run_engine(eng):
         if _otrace._state.enabled:

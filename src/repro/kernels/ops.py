@@ -95,8 +95,11 @@ def _class_plan_cached(rows: tuple, c: int, t: int) -> tuple:
     quarantine the entry and fall through to fresh planning."""
     from .. import store as _store
 
-    return _store.class_plan_through(
-        rows, c, t, lambda: _build_class_plan(rows, c, t))
+    def build():
+        with _otrace.span("plan.class", n=len(rows), t=t):
+            return _build_class_plan(rows, c, t)
+
+    return _store.class_plan_through(rows, c, t, build)
 
 
 def bmmc_plans(bmmc: Bmmc, t: int):

@@ -4,8 +4,9 @@ Three layers, all zero-cost while disabled (the default — every
 instrumentation site is one module-attribute check, and nothing is
 recorded inside kernels or compiled jaxprs):
 
-* :mod:`.trace`   — hierarchical spans (program > stage > kernel
-  dispatch), recorded at dispatch/trace time on the host.
+* :mod:`.trace`   — hierarchical spans (entry > program > stage >
+  kernel dispatch, plus plan-cache misses), recorded at dispatch/trace
+  time on the host, each also a ``repro.<name>`` profiler annotation.
 * :mod:`.metrics` — labeled counters + histograms: kernel-class
   dispatch counts, fold_free eliminations, DMA descriptors, modeled
   round trips, request/step latency.

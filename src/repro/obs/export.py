@@ -53,7 +53,7 @@ def snapshot() -> dict:
         "histograms": {_fmt_key(k): s for k, s in
                        sorted(_metrics.histograms().items())},
         "caches": cache_stats(),
-        "trace_events": len(_trace.events()),
+        "trace_events": _trace.count(),
         "model_vs_measured": model_vs_measured(),
     }
 
@@ -99,7 +99,7 @@ def report(file=None) -> str:
     state = ("enabled" if _trace.enabled() else "disabled")
     lines.append(f"telemetry: {state} (sync="
                  f"{_trace._state.sync}); spans recorded: "
-                 f"{len(_trace.events())} (dropped {_trace.dropped()})")
+                 f"{_trace.count()} (dropped {_trace.dropped()})")
 
     kc = _metrics.kernel_counts()
     if kc:

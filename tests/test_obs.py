@@ -14,10 +14,14 @@ Pins the contracts of the :mod:`repro.obs` layer:
   recording: the per-class counts describe the program, not the data),
   and warm same-shape calls add no dispatch counts at all;
 * ``cache_stats()`` covers every executor/ops cache and
-  ``clear_caches()`` resets the telemetry with them.
+  ``clear_caches()`` resets the telemetry with them;
+* enabled spans reach the profiler's host plane as ``repro.<name>``
+  annotations, nested in the caller's own; disabled spans build no
+  annotation; the ``plan.*`` spans fire on plan-cache misses only.
 """
 import json
 import random
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -194,8 +198,8 @@ def test_counter_deltas_independent_of_batch_size():
 def test_cache_stats_covers_every_executor_cache():
     stats = cache_stats()
     assert {"geom", "block", "lane", "program", "fused_plan", "w_planar",
-            "lowered", "clustered", "model_round_trips", "plans",
-            "class_plan", "compiled_exprs"} <= set(stats)
+            "lowered", "clustered", "plans", "class_plan",
+            "compiled_exprs"} <= set(stats)
     for name, info in stats.items():
         assert info.hits >= 0 and info.misses >= 0, name
         assert info.currsize >= 0, name
@@ -217,6 +221,95 @@ def test_clear_caches_resets_telemetry_too():
     assert obs.counters() == {} and obs.events() == []
     assert obs.histograms() == {}
     for name in ("geom", "block", "lane", "program", "fused_plan",
-                 "clustered", "model_round_trips", "class_plan"):
+                 "clustered", "class_plan"):
         assert cache_stats()[name].currsize == 0, name
     assert obs.enabled()    # reset drops data, not the enabled flag
+
+
+# ---------------------------------------------------------------------------
+# The profiler sink: spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+_CALL_SPANS = ("repro.entry.resolve", "repro.entry.route",
+               "repro.entry.apply", "repro.program.lookup",
+               "repro.program.enqueue")
+
+
+@pytest.mark.tier1
+def test_call_spans_reach_the_profiler_host_plane(tmp_path):
+    """A warm 2^8 sort call inside a caller's annotation, under the
+    profiler: each per-call span is on the host plane, inside the
+    caller's span."""
+    from jax.profiler import ProfileData
+    n = 8
+    f = compile_expr(sort_expr(n), engine="pallas")
+    x = _payload((1 << n,), 4)
+    jax.block_until_ready(f(x))
+    obs.enable(sync=False)
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("caller.call"):
+            y = f(x)
+        jax.block_until_ready(y)
+    obs.disable()
+    path = next(Path(tmp_path).rglob("*.xplane.pb"))
+    found = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    found.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    (lo, hi), = found["caller.call"]
+    for name in _CALL_SPANS:
+        assert len(found.get(name, ())) == 1, (name, sorted(found))
+        (a, b), = found[name]
+        assert lo <= a <= b <= hi, name
+    # the executable's lookup and call lie inside the routed dispatch
+    (lo, hi), = found["repro.entry.apply"]
+    for name in ("repro.program.lookup", "repro.program.enqueue"):
+        (a, b), = found[name]
+        assert lo <= a <= b <= hi, name
+    # the in-memory buffer still records the same spans
+    recorded = {"repro." + e["name"] for e in obs.events()}
+    assert set(_CALL_SPANS) <= recorded
+
+
+@pytest.mark.tier1
+def test_disabled_span_builds_no_annotation(monkeypatch):
+    """Disabled, a span site is one attribute check: the annotation
+    class is never constructed and nothing is recorded."""
+    n = 8
+    f = compile_expr(sort_expr(n), engine="pallas")
+    x = _payload((1 << n,), 5)
+    jax.block_until_ready(f(x))
+
+    def boom(*args, **kwargs):
+        raise AssertionError("TraceAnnotation built while disabled")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    assert not obs.enabled()
+    with obs.span("ghost"):
+        pass
+    jax.block_until_ready(f(x))
+    assert obs.events() == [] and obs.counters() == {}
+
+
+@pytest.mark.tier1
+def test_plan_spans_fire_on_cache_misses_only():
+    """After ``clear_caches()`` the first call plans (lowering,
+    clustering, the backward plan); a second same-shape call hits every
+    plan cache and records no ``plan.*`` span."""
+    clear_caches()
+    n = 8
+    f = compile_expr(sort_expr(n), engine="pallas")
+    obs.enable(sync=False)
+
+    def plan_spans(seed):
+        obs.reset()
+        jax.block_until_ready(f(_payload((1 << n,), seed)))
+        return {e["name"] for e in obs.events()
+                if e["name"].startswith("plan.")}
+
+    first = plan_spans(6)
+    assert {"plan.lower", "plan.cluster", "plan.bwd"} <= first, first
+    assert plan_spans(7) == set()
