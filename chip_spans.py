@@ -13,7 +13,8 @@ the seed), then:
    spans (``plan_s``: planning paid on plan-cache misses), each kind's
    count and time, the first calls' time beyond the second less the
    compiles in them (as the benchmark's ``trace_plan_s``), and the
-   ``dma.descriptors`` counter over the number of programs;
+   ``dma.descriptors`` and ``dma.box_sides{side}`` counters over the
+   number of programs;
 2. window: the benchmark's own closed loop and traced sub-window
    (``harness.Window``), with telemetry on all through it. Over the
    traced calls it reports ``host_call_us`` (as the benchmark's reader
@@ -87,6 +88,8 @@ def warm_up(calls, x, clog) -> dict:
             second += time.perf_counter() - t1
         plans = [e for e in obs.events() if e["name"].startswith("plan.")]
         descriptors = obs.counter_total("dma.descriptors")
+        box_sides = {side: obs.counter_value("dma.box_sides", side=side)
+                     for side in ("in", "out")}
     finally:
         obs.disable()
         obs.reset()
@@ -97,7 +100,9 @@ def warm_up(calls, x, clog) -> dict:
         kinds[e["name"]] = (count + 1, total + e["dur"] / 1e6)
     return {"trace_plan_s": first - second - compile_s, "plan_s": union_s(plans),
             "plan_kinds": {k: {"count": c, "s": s} for k, (c, s) in sorted(kinds.items())},
-            "dma_descriptors_per_call": descriptors / len(calls)}
+            "dma_descriptors_per_call": descriptors / len(calls),
+            "dma_box_sides_per_call": {k: v / len(calls)
+                                       for k, v in box_sides.items()}}
 
 
 def readings(trace: dict, extra: list, enqueue_s: list) -> dict:

@@ -338,11 +338,13 @@ def _fused_forward(x, fs, engine, batched):
         t = _fused_tile(x, fs, batched)
         if t is not None:
             if _otrace._state.enabled:
+                from ..kernels import ops
                 plans, _ = _fused_plan_cached(fs, t)
                 _ometrics.inc("dispatch.kernel", kernel="fused")
                 _ometrics.inc("model.round_trips", len(plans))
                 _ometrics.inc("dma.descriptors",
                               sum(p.dma_descriptors() for p in plans))
+                ops.count_box_sides(plans)
                 with _otrace.span("kernel.fused", stages=len(fs.stages),
                                   passes=len(plans), t=t):
                     return _fused_pallas(x, fs, t, batched=batched)
@@ -846,6 +848,7 @@ def _fused_bwd_impl(fs, engine, batched, x, ct):
         t = _fused_tile(x, fs, batched)
         if t is not None and _fused_bwd_kernel_plan(fs, t) is not None:
             if _otrace._state.enabled:
+                from ..kernels import ops
                 plans, _, extra = _fused_bwd_kernel_plan(fs, t)
                 rt = 1 + sum(len(ip) for ip in extra)
                 _ometrics.inc("dispatch.kernel", kernel="fused")
@@ -857,9 +860,11 @@ def _fused_bwd_impl(fs, engine, batched, x, ct):
                 _ometrics.inc(
                     "dma.descriptors",
                     p0.dma_descriptors()
-                    + p0.n_tiles * (p0.rows_per_tile // p0.in_run)
+                    + p0.n_tiles * p0.side_descriptors()[0]
                     + sum(p.dma_descriptors()
                           for ip in extra for p in ip))
+                ops.count_box_sides(
+                    (p0,) + tuple(p for ip in extra for p in ip))
                 with _otrace.span("kernel.fused_bwd", stages=len(fs.stages),
                                   passes=rt, t=t):
                     return _fused_bwd_pallas(fs, t, batched, x, ct)

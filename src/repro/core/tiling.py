@@ -84,8 +84,90 @@ def _run_length(rows: np.ndarray) -> int:
     return run
 
 
+def _bit_runs(mask: int) -> tuple:
+    """Maximal runs ``((lo, width), ...)`` of the set bits of ``mask``,
+    lowest first."""
+    runs, lo = [], 0
+    while mask >> lo:
+        if (mask >> lo) & 1:
+            w = 0
+            while (mask >> (lo + w)) & 1:
+                w += 1
+            runs.append((lo, w))
+            lo += w
+        else:
+            lo += 1
+    return tuple(runs)
+
+
+def row_box(rows: np.ndarray) -> Optional[tuple]:
+    """The box a side's row table forms, or None.
+
+    A side is a box when every tile's rows are its first row with every
+    combination of a fixed set of row-id bits, clear in that first row,
+    and slot ``r`` sets those bits to the bits of ``r`` in ascending
+    order. Returns the set as runs ``((lo, width), ...)`` of consecutive
+    row-id bits, lowest first: the kernel copies such a side with one
+    strided descriptor over a view with one axis per run."""
+    rpt = rows.shape[1]
+    imgs = [int(rows[0, 1 << k] ^ rows[0, 0])
+            for k in range(rpt.bit_length() - 1)]
+    if not imgs or any(v <= 0 or v & (v - 1) for v in imgs) or any(
+            a >= b for a, b in zip(imgs, imgs[1:])):
+        return None
+    slots = np.arange(rpt, dtype=np.int64)
+    want = np.zeros(rpt, dtype=np.int64)
+    for k, v in enumerate(imgs):
+        want |= ((slots >> k) & 1) * v
+    if not np.array_equal(rows, rows[:, :1] | want[None, :]):
+        return None
+    return _bit_runs(sum(imgs))
+
+
+def _box_basis(vecs: list, imgs: list) -> Optional[tuple]:
+    """``(dirs, mask)``: combinations of ``vecs`` whose images are the
+    single bits of ``mask``, the images' span, lowest bit first, when
+    that span is a box (spanned by unit row-id bits); None otherwise.
+    ``imgs[k]`` is the row-id image of ``vecs[k]`` under a linear map,
+    so a combination's image is the XOR of its parts' images."""
+    mask = 0
+    for h in imgs:
+        mask |= h
+    if not imgs or bin(mask).count("1") != len(imgs):
+        return None
+    todo, done = list(zip(imgs, vecs)), []
+    for b in range(mask.bit_length()):   # Gauss-Jordan on the images
+        if (mask >> b) & 1:
+            h, v = todo.pop(next(i for i, (h, _) in enumerate(todo)
+                                 if (h >> b) & 1))
+            todo, done = ([(h2 ^ h, v2 ^ v) if (h2 >> b) & 1 else (h2, v2)
+                           for h2, v2 in part] for part in (todo, done))
+            done.append((h, v))
+    return [v for _, v in done], mask
+
+
+class _Descriptors:
+    """DMA descriptor counts of a tile plan: a box side takes one
+    descriptor per tile, any other side one per run of ``in_run`` /
+    ``out_run`` consecutive rows."""
+
+    def side_descriptors(self) -> tuple:
+        """Descriptors per tile, (input reads, output writes)."""
+        rpt = self.rows_per_tile
+        return (1 if self.in_box else rpt // self.in_run,
+                1 if self.out_box else rpt // self.out_run)
+
+    def dma_descriptors(self) -> int:
+        """Total HBM DMA descriptors issued (reads + writes)."""
+        return self.n_tiles * sum(self.side_descriptors())
+
+    def bytes_per_descriptor(self, itemsize: int) -> tuple:
+        tile = self.rows_per_tile * self.row_len * itemsize
+        return tuple(tile // k for k in self.side_descriptors())
+
+
 @dataclasses.dataclass(frozen=True)
-class TilePlan:
+class TilePlan(_Descriptors):
     """Offline execution plan for one tiled-BMMC pass.
 
     ``row_dirs`` are the witness *directions* spanning the tile's row
@@ -126,16 +208,15 @@ class TilePlan:
     def row_len(self) -> int:
         return 1 << self.t
 
-    # -- modeled memory transactions (the quantity behind the paper's
-    # -- bandwidth results; used by the benchmark harness) -------------------
-    def dma_descriptors(self) -> int:
-        """Total HBM DMA descriptors issued (reads + writes)."""
-        per_tile = self.rows_per_tile // self.in_run + self.rows_per_tile // self.out_run
-        return self.n_tiles * per_tile
+    @functools.cached_property
+    def in_box(self) -> Optional[tuple]:
+        """:func:`row_box` of the rows each tile reads."""
+        return row_box(self.in_rows)
 
-    def bytes_per_descriptor(self, itemsize: int) -> tuple:
-        return (self.in_run * self.row_len * itemsize,
-                self.out_run * self.row_len * itemsize)
+    @functools.cached_property
+    def out_box(self) -> Optional[tuple]:
+        """:func:`row_box` of the rows each tile writes."""
+        return row_box(self.out_rows)
 
     def audit(self) -> "TilePlan":
         """Descriptor-bounds + semantic audit (guard ring 1): every
@@ -272,11 +353,46 @@ def _square_up(tb: list, deficit: int) -> tuple:
     return tb[:k], tb[k:]
 
 
+def _out_side(bmmc: Bmmc, t: int, out_pos: list, tb: list) -> tuple:
+    """``(dirs, off, box)`` of a tile's output side: output slot ``r'``
+    holds the images of ``base ^ off ^ XOR(dirs[k] for bits k of r')``.
+    When the rows written form a box that no tile base moves, the unit
+    directions of ``out_pos`` are re-based so the slot bits enumerate
+    the box's row-id bits in ascending order, and ``off`` clears those
+    bits in every tile's first row; otherwise the units stay as they
+    are and ``box`` is None."""
+    units = [1 << p for p in out_pos]
+
+    def hi(v: int) -> int:
+        return f2.matvec(bmmc.rows, v) >> t
+    got = _box_basis(units, [hi(v) for v in units])
+    if got is None or any(hi(1 << q) & got[1] for q in tb):
+        return units, 0, None
+    dirs, mask = got
+    off = 0   # each tile's first row carries c's bits of the box
+    for v in dirs:
+        if hi(v) & (bmmc.c >> t):
+            off ^= v
+    return dirs, off, _bit_runs(mask)
+
+
+def _in_box(row_dirs: list, t: int) -> tuple:
+    """``(dirs, box)`` of a tile's input side: ``row_dirs`` re-based so
+    the slot bits enumerate the box's row-id bits in ascending order
+    when the rows read form a box (the tile bases, unit positions
+    outside the directions' span, never touch it); else unchanged."""
+    got = _box_basis(row_dirs, [v >> t for v in row_dirs])
+    if got is None:
+        return row_dirs, None
+    return got[0], _bit_runs(got[1])
+
+
 def _classic_layout(bmmc: Bmmc, t: int) -> Optional[tuple]:
-    """``(cols, n_over, row_pos, out_pos, tb)`` of the classic tiled plan:
-    tile slot ``r`` reads input row bits ``row_pos``, output slot ``r'``
-    enumerates ``out_pos``, and ``tb`` indexes the tiles. None if
-    ``bmmc`` is not tiled for this ``t``."""
+    """``(cols, n_over, row_pos, (out_dirs, out_off, out_box), tb)`` of
+    the classic tiled plan: tile slot ``r`` reads input row bits
+    ``row_pos`` (ascending: the input side is always a box), output
+    slot ``r'`` is enumerated by :func:`_out_side`, and ``tb`` indexes
+    the tiles. None if ``bmmc`` is not tiled for this ``t``."""
     n = bmmc.n
     if t > n:
         return None
@@ -293,7 +409,8 @@ def _classic_layout(bmmc: Bmmc, t: int) -> Optional[tuple]:
     tb = sorted(set(range(n)) - low - r_set)
     assert len(tb) == n - 2 * t + n_over
     extra, tb = _square_up(tb, n_over)
-    return (cols, n_over, sorted(r_not_l + extra), l_not_r + extra, tb)
+    return (cols, n_over, sorted(r_not_l + extra),
+            _out_side(bmmc, t, l_not_r + extra, tb), tb)
 
 
 def plan_tiled(bmmc: Bmmc, t: int) -> Optional[TilePlan]:
@@ -301,7 +418,7 @@ def plan_tiled(bmmc: Bmmc, t: int) -> Optional[TilePlan]:
     lay = _classic_layout(bmmc, t)
     if lay is None:
         return None
-    cols, n_over, row_pos, out_pos, tb = lay
+    cols, n_over, row_pos, (out_dirs, out_off, _), tb = lay
     rpt = 1 << len(row_pos)                  # rows per tile
     n_tiles = 1 << len(tb)
     row_len = 1 << t
@@ -320,7 +437,7 @@ def plan_tiled(bmmc: Bmmc, t: int) -> Optional[TilePlan]:
         for r in range(rpt):
             in_rows[g, r] = (base | _scatter_bits(r, row_pos)) >> t
         for rp in range(rpt):
-            y = bmmc.apply(base | _scatter_bits(rp, out_pos))
+            y = bmmc.apply(base ^ out_off ^ _xor_dirs(rp, out_dirs))
             out_rows[g, rp] = y >> t
 
     # Intra-tile gather table for tile 0 (other tiles differ by xor_low only).
@@ -420,11 +537,13 @@ def _out_low_positions(bmmc: Bmmc, t: int, count: int) -> list:
 
 
 def _general_layout(bmmc: Bmmc, t: int) -> Optional[tuple]:
-    """``(a, row_dirs, out_pos, tb)`` of the generalized plan: the
-    witness directions plus the absorbed thread-block unit directions,
-    the positions enumerating a tile's output rows, and the remaining
-    thread-block positions. None when the tile would exceed the array
-    (``n - 2t + a < 0``, only possible for t > n/2)."""
+    """``(a, (row_dirs, in_box), (out_dirs, out_off, out_box), tb)`` of
+    the generalized plan: the witness directions plus the absorbed
+    thread-block unit directions (:func:`_in_box`), the output side
+    enumerated from the low positions whose images span a tile's
+    output rows (:func:`_out_side`), and the remaining thread-block
+    positions. None when the tile would exceed the array (``n - 2t + a
+    < 0``, only possible for t > n/2)."""
     n = bmmc.n
     if not 0 < t <= n:
         return None
@@ -433,7 +552,8 @@ def _general_layout(bmmc: Bmmc, t: int) -> Optional[tuple]:
         return None
     extra, tb = _square_up(_tb_complement(row_dirs, t, n), a)
     out_pos = _out_low_positions(bmmc, t, t - a) + extra
-    return a, row_dirs + [1 << p for p in extra], out_pos, tb
+    return (a, _in_box(row_dirs + [1 << p for p in extra], t),
+            _out_side(bmmc, t, out_pos, tb), tb)
 
 
 def plan_general(bmmc: Bmmc, t: int) -> Optional[TilePlan]:
@@ -443,7 +563,7 @@ def plan_general(bmmc: Bmmc, t: int) -> Optional[TilePlan]:
     lay = _general_layout(bmmc, t)
     if lay is None:
         return None
-    a, row_dirs, out_pos, tb = lay
+    a, (row_dirs, _), (out_dirs, out_off, _), tb = lay
     low_mask = (1 << t) - 1
     rpt = 1 << len(row_dirs)
     n_tiles = 1 << len(tb)
@@ -458,7 +578,7 @@ def plan_general(bmmc: Bmmc, t: int) -> Optional[TilePlan]:
         for r in range(rpt):
             in_rows[g, r] = (base ^ _xor_dirs(r, row_dirs)) >> t
         for rp in range(rpt):
-            y = bmmc.apply(base ^ _scatter_bits(rp, out_pos))
+            y = bmmc.apply(base ^ out_off ^ _xor_dirs(rp, out_dirs))
             out_rows[g, rp] = y >> t
 
     order = np.argsort(in_rows[0])
@@ -587,12 +707,13 @@ def compute_tables(plan: TilePlan, prefix: Bmmc,
 
 
 @dataclasses.dataclass(frozen=True)
-class PlanStats:
+class PlanStats(_Descriptors):
     """Analytic plan statistics — O(n^2) bit math, no table enumeration.
 
-    Matches TilePlan's n_over / rows_per_tile / n_tiles / in_run / out_run
-    (property-tested against the enumerated tables), usable at paper scale
-    (n = 30 => 2^20 tiles) where building per-tile tables is infeasible.
+    Matches TilePlan's n_over / rows_per_tile / n_tiles / in_run /
+    out_run / in_box / out_box (property-tested against the enumerated
+    tables), usable at paper scale (n = 30 => 2^20 tiles) where building
+    per-tile tables is infeasible.
     """
     n: int
     t: int
@@ -602,25 +723,20 @@ class PlanStats:
     row_len: int
     in_run: int
     out_run: int
-
-    def dma_descriptors(self) -> int:
-        per_tile = (self.rows_per_tile // self.in_run
-                    + self.rows_per_tile // self.out_run)
-        return self.n_tiles * per_tile
-
-    def bytes_per_descriptor(self, itemsize: int) -> tuple:
-        return (self.in_run * self.row_len * itemsize,
-                self.out_run * self.row_len * itemsize)
+    in_box: Optional[tuple]
+    out_box: Optional[tuple]
 
 
-def _out_run_bits(bmmc: Bmmc, t: int, out_pos: list, tb: list) -> int:
+def _out_run_bits(bmmc: Bmmc, t: int, out_side: tuple, tb: list) -> int:
     """log2 of the output DMA run: ``out_rows[g, r']`` is affine in the
     bits of ``r'``; runs of 2^k are consecutive iff bit i of r' moves
     y_high by exactly 2^i for i < k and no other contribution (higher
-    slot bits, base bits, c) touches the low k bits of y_high."""
-    deltas = [f2.matvec(bmmc.rows, 1 << pos) >> t for pos in out_pos]
+    slot bits, base bits, the first slot's offset and c) touches the
+    low k bits of y_high."""
+    out_dirs, out_off, _ = out_side
+    deltas = [f2.matvec(bmmc.rows, v) >> t for v in out_dirs]
     others = [f2.matvec(bmmc.rows, 1 << pos) >> t for pos in tb]
-    others.append(bmmc.c >> t)
+    others.append((f2.matvec(bmmc.rows, out_off) ^ bmmc.c) >> t)
     k_out = 0
     while k_out < len(deltas):
         k = k_out + 1
@@ -639,15 +755,17 @@ def plan_stats(bmmc: Bmmc, t: int) -> Optional[PlanStats]:
     lay = _classic_layout(bmmc, t)
     if lay is None:
         return None
-    _, n_over, row_pos, out_pos, tb = lay
+    _, n_over, row_pos, out_side, tb = lay
     # input-run: rows consecutive iff the low row positions are t, t+1, ...
     k_in = 0
     while k_in < len(row_pos) and row_pos[k_in] == t + k_in:
         k_in += 1
-    k_out = _out_run_bits(bmmc, t, out_pos, tb)
+    k_out = _out_run_bits(bmmc, t, out_side, tb)
+    in_box = _bit_runs(sum(1 << (p - t) for p in row_pos)) or None
     return PlanStats(n=bmmc.n, t=t, n_over=n_over, n_tiles=1 << len(tb),
                      rows_per_tile=1 << len(row_pos), row_len=1 << t,
-                     in_run=1 << k_in, out_run=1 << k_out)
+                     in_run=1 << k_in, out_run=1 << k_out,
+                     in_box=in_box, out_box=out_side[2])
 
 
 def plan_stats_general(bmmc: Bmmc, t: int) -> Optional[PlanStats]:
@@ -655,7 +773,7 @@ def plan_stats_general(bmmc: Bmmc, t: int) -> Optional[PlanStats]:
     lay = _general_layout(bmmc, t)
     if lay is None:
         return None
-    a, row_dirs, out_pos, tb = lay
+    a, (row_dirs, in_box), out_side, tb = lay
     # input-run: in_rows[g, r] counts binarily in r iff high(row_dirs[i])
     # == 2^i for i < k and nothing else (higher dirs, tb base bits)
     # touches the low k row-id bits.
@@ -670,10 +788,11 @@ def plan_stats_general(bmmc: Bmmc, t: int) -> Optional[PlanStats]:
         if not ok:
             break
         k_in = k
-    k_out = _out_run_bits(bmmc, t, out_pos, tb)
+    k_out = _out_run_bits(bmmc, t, out_side, tb)
     return PlanStats(n=bmmc.n, t=t, n_over=a, n_tiles=1 << len(tb),
                      rows_per_tile=1 << len(row_dirs), row_len=1 << t,
-                     in_run=1 << k_in, out_run=1 << k_out)
+                     in_run=1 << k_in, out_run=1 << k_out,
+                     in_box=in_box, out_box=out_side[2])
 
 
 def stats_bmmc(bmmc: Bmmc, t: int) -> list:

@@ -153,10 +153,11 @@ def truncate_parity_table(fs, t: int):
 # disk faults (the durable plan store; DESIGN.md §15)
 # ---------------------------------------------------------------------------
 
-def _skewed_entry(data: bytes) -> bytes:
-    """Re-sign ``data``'s header with a bumped schema version — an
-    *intact* entry from a different planner generation, the one fault
-    class that must read as a miss, never a quarantine."""
+def _skewed_entry(data: bytes, code: str | None = None) -> bytes:
+    """Re-sign ``data``'s header with a bumped schema version, or with
+    the planner generation ``code`` — an *intact* entry from a different
+    planner generation, the one fault class that must read as a miss,
+    never a quarantine."""
     import json
     import struct
 
@@ -165,7 +166,10 @@ def _skewed_entry(data: bytes) -> bytes:
     hlen, _ = struct.unpack_from(_codec._HEADER_FMT, data, len(_codec.MAGIC))
     hj = data[_codec._PREFIX_LEN:_codec._PREFIX_LEN + hlen]
     header = json.loads(hj)
-    header["schema"] = header["schema"] + 1
+    if code is None:
+        header["schema"] = header["schema"] + 1
+    else:
+        header["code"] = code
     hj2 = json.dumps(header, sort_keys=True).encode("utf-8")
     return b"".join((
         _codec.MAGIC,

@@ -10,8 +10,12 @@ pipeline):
 * Row id tables (``in_rows``/``out_rows``), the per-tile lane XOR and the
   intra-tile gather table ``src0`` are *offline* artifacts (scalar-prefetch /
   VMEM constants), mirroring the paper's offline codegen setting.
-* Consecutive row ids are merged into one DMA descriptor (``in_run`` /
-  ``out_run`` rows per copy) — the DMA analogue of the paper's §4.3
+* A tile side (the rows read or the rows written) whose row ids form a
+  *box* — the tile's first row with every combination of a fixed set of
+  row-id bits (``TilePlan.in_box`` / ``out_box``) — is ONE strided DMA
+  descriptor over an HBM view with one axis per run of those bits. Any
+  other side merges consecutive row ids into one descriptor per
+  ``in_run`` / ``out_run`` rows — the DMA analogue of the paper's §4.3
   iteration amortization.
 * The intra-tile permutation is a flat VMEM gather
   ``out.flat[j] = tile.flat[src0[j ^ xor_low[g]]]`` — the per-tile XOR trick
@@ -310,6 +314,63 @@ class _Epilogues:
         return self.wbuf[...]
 
 
+def _box_axes(box: tuple, nbits: int) -> tuple:
+    """``(lo, width, varies)`` per HBM axis of a box side's row view,
+    highest row-id bits first: one axis per run of the box and one per
+    stretch of fixed bits around them."""
+    axes, top = [], nbits
+    for lo, w in reversed(box):
+        if top > lo + w:
+            axes.append((lo + w, top - lo - w, False))
+        axes.append((lo, w, True))
+        top = lo
+    if top:
+        axes.append((0, top, False))
+    return tuple(axes)
+
+
+def _side_shapes(layout, n_rows: int, rpt: int) -> tuple:
+    """(HBM row dims, VMEM slot row dims) of one tile side: a box
+    (``layout`` a tuple of runs) splits the row id into
+    :func:`_box_axes`, the slot into its runs; a plain side (``layout``
+    the run of consecutive rows per descriptor) keeps one row axis."""
+    if not isinstance(layout, tuple):
+        return (n_rows,), (rpt,)
+    axes = _box_axes(layout, n_rows.bit_length() - 1)
+    return (tuple(1 << w for _, w, _ in axes),
+            tuple(1 << w for _, w, v in axes if v))
+
+
+class _Side:
+    """The DMA descriptors that move one tile side between its HBM rows
+    and a VMEM slot: one strided descriptor for a box, whose fixed axes
+    index the tile's first row id (``rows[g, 0]``), else one per run of
+    consecutive row ids."""
+
+    def __init__(self, layout, rpt: int, n_rows: int, b):
+        self.b = b   # batch index (batched kernels) or None
+        if isinstance(layout, tuple):
+            self.axes = _box_axes(layout, n_rows.bit_length() - 1)
+            self.count = 1
+        else:
+            self.axes, self.run = None, layout
+            self.count = rpt // layout
+
+    def copy(self, hbm, rows, buf, sem, g, slot, i, *, write: bool):
+        lead = () if self.b is None else (self.b,)
+        if self.axes is None:
+            far = hbm.at[lead + (pl.ds(rows[g, i * self.run], self.run),)]
+            near = buf.at[slot, pl.ds(i * self.run, self.run)]
+        else:
+            r0 = rows[g, 0]
+            far = hbm.at[lead + tuple(
+                slice(None) if varies else (r0 >> lo) & ((1 << w) - 1)
+                for lo, w, varies in self.axes)]
+            near = buf.at[slot]
+        src, dst = (near, far) if write else (far, near)
+        return pltpu.make_async_copy(src, dst, sem.at[slot])
+
+
 def _take_refs(refs, epis, nb_scalar_head: int, n_x: int):
     it = iter(refs)
     head = tuple(next(it) for _ in range(nb_scalar_head))
@@ -324,8 +385,8 @@ def _take_refs(refs, epis, nb_scalar_head: int, n_x: int):
     return head, scalars, xs, vmem, hbm, it
 
 
-def _tile_kernel(*refs, rpt: int, row_len: int, d: int, in_run: int,
-                 out_run: int, batched: bool, n_tiles: int,
+def _tile_kernel(*refs, rpt: int, row_len: int, d: int, n_rows: int,
+                 in_layout, out_layout, batched: bool, n_tiles: int,
                  num_buffers: int, steps: tuple, epis: tuple,
                  map_fns: tuple):
     """The fused-stage megakernel: one invocation = all tiles of one pass.
@@ -361,42 +422,34 @@ def _tile_kernel(*refs, rpt: int, row_len: int, d: int, in_run: int,
         e[0] == "bfly" for e in epis) else (None, None)
 
     b = pl.program_id(0) if batched else None
-
-    def rows_of(ref, r0, run):
-        return (ref.at[b, pl.ds(r0, run)] if batched
-                else ref.at[pl.ds(r0, run)])
-
-    n_in = rpt // in_run
-    n_out = rpt // out_run
+    src = _Side(in_layout, rpt, n_rows, b)
+    dst = _Side(out_layout, rpt, n_rows, b)
 
     # DMA descriptors are reconstructed at wait time (waiting only touches
     # the semaphore), so start/wait can live in different loop iterations.
     def in_copy(g, slot, i):
-        return pltpu.make_async_copy(
-            rows_of(x_hbm, in_rows[g, i * in_run], in_run),
-            tiles.at[slot, pl.ds(i * in_run, in_run)], in_sems.at[slot])
+        return src.copy(x_hbm, in_rows, tiles, in_sems, g, slot, i,
+                        write=False)
 
     def out_copy(g, slot, i):
-        return pltpu.make_async_copy(
-            obuf.at[slot, pl.ds(i * out_run, out_run)],
-            rows_of(o_hbm, out_rows[g, i * out_run], out_run),
-            out_sems.at[slot])
+        return dst.copy(o_hbm, out_rows, obuf, out_sems, g, slot, i,
+                        write=True)
 
     def start_in(g):
         slot = jax.lax.rem(g, nb)
-        _each(n_in, lambda i: in_copy(g, slot, i).start())
+        _each(src.count, lambda i: in_copy(g, slot, i).start())
 
     def wait_in(g):
         slot = jax.lax.rem(g, nb)
-        _each(n_in, lambda i: in_copy(g, slot, i).wait())
+        _each(src.count, lambda i: in_copy(g, slot, i).wait())
 
     def start_out(g):
         slot = jax.lax.rem(g, nb)
-        _each(n_out, lambda i: out_copy(g, slot, i).start())
+        _each(dst.count, lambda i: out_copy(g, slot, i).start())
 
     def wait_out(g):
         slot = jax.lax.rem(g, nb)
-        _each(n_out, lambda i: out_copy(g, slot, i).wait())
+        _each(dst.count, lambda i: out_copy(g, slot, i).wait())
 
     ti = _TileIndex(rpt, row_len, d)
 
@@ -460,8 +513,8 @@ def _tile_kernel(*refs, rpt: int, row_len: int, d: int, in_run: int,
         wait_out(n_tiles - 1 - k)
 
 
-def _tile_bwd_kernel(*refs, rpt: int, row_len: int, d: int, in_run: int,
-                     out_run: int, batched: bool, n_tiles: int,
+def _tile_bwd_kernel(*refs, rpt: int, row_len: int, d: int, n_rows: int,
+                     in_layout, out_layout, batched: bool, n_tiles: int,
                      num_buffers: int, steps: tuple, epis: tuple,
                      map_fns: tuple):
     """The gradient megakernel: the exact transpose of one fused pass.
@@ -489,48 +542,39 @@ def _tile_bwd_kernel(*refs, rpt: int, row_len: int, d: int, in_run: int,
         e[0] == "bfly" for e in epis) else (None, None)
 
     b = pl.program_id(0) if batched else None
-
-    def rows_of(ref, r0, run):
-        return (ref.at[b, pl.ds(r0, run)] if batched
-                else ref.at[pl.ds(r0, run)])
-
-    n_in = rpt // in_run
-    n_out = rpt // out_run
+    fwd_in = _Side(in_layout, rpt, n_rows, b)
+    fwd_out = _Side(out_layout, rpt, n_rows, b)
 
     def x_copy(g, slot, i):
-        return pltpu.make_async_copy(
-            rows_of(x_hbm, in_rows[g, i * in_run], in_run),
-            xtiles.at[slot, pl.ds(i * in_run, in_run)], in_sems.at[slot])
+        return fwd_in.copy(x_hbm, in_rows, xtiles, in_sems, g, slot, i,
+                           write=False)
 
     def ct_copy(g, slot, i):
-        return pltpu.make_async_copy(
-            rows_of(ct_hbm, out_rows[g, i * out_run], out_run),
-            ctiles.at[slot, pl.ds(i * out_run, out_run)], in_sems.at[slot])
+        return fwd_out.copy(ct_hbm, out_rows, ctiles, in_sems, g, slot, i,
+                            write=False)
 
     def out_copy(g, slot, i):
-        # the transpose WRITES where the forward READ: in_rows runs
-        return pltpu.make_async_copy(
-            obuf.at[slot, pl.ds(i * in_run, in_run)],
-            rows_of(o_hbm, in_rows[g, i * in_run], in_run),
-            out_sems.at[slot])
+        # the transpose WRITES where the forward READ: the input side
+        return fwd_in.copy(o_hbm, in_rows, obuf, out_sems, g, slot, i,
+                           write=True)
 
     def start_in(g):
         slot = jax.lax.rem(g, nb)
-        _each(n_in, lambda i: x_copy(g, slot, i).start())
-        _each(n_out, lambda i: ct_copy(g, slot, i).start())
+        _each(fwd_in.count, lambda i: x_copy(g, slot, i).start())
+        _each(fwd_out.count, lambda i: ct_copy(g, slot, i).start())
 
     def wait_in(g):
         slot = jax.lax.rem(g, nb)
-        _each(n_in, lambda i: x_copy(g, slot, i).wait())
-        _each(n_out, lambda i: ct_copy(g, slot, i).wait())
+        _each(fwd_in.count, lambda i: x_copy(g, slot, i).wait())
+        _each(fwd_out.count, lambda i: ct_copy(g, slot, i).wait())
 
     def start_out(g):
         slot = jax.lax.rem(g, nb)
-        _each(n_in, lambda i: out_copy(g, slot, i).start())
+        _each(fwd_in.count, lambda i: out_copy(g, slot, i).start())
 
     def wait_out(g):
         slot = jax.lax.rem(g, nb)
-        _each(n_in, lambda i: out_copy(g, slot, i).wait())
+        _each(fwd_in.count, lambda i: out_copy(g, slot, i).wait())
 
     ti = _TileIndex(rpt, row_len, d)
 
@@ -625,21 +669,26 @@ def _tile_bwd_kernel(*refs, rpt: int, row_len: int, d: int, in_run: int,
 
 def _pass_args(x, geometry, batched, epilogue, epi_scalar, epi_vmem):
     """Shared wrapper work of the forward and gradient passes: the HBM
-    row view (trailing ``d`` folded into the lanes), the per-epilogue
-    kernel args and their specs, and the scratch the epilogues need.
+    row views and VMEM slot shapes of the input and output sides
+    (trailing ``d`` folded into the lanes), the per-epilogue kernel args
+    and their specs, and the scratch the epilogues need.
 
-    Rows are viewed as ``(rows, 1, lanes)`` in HBM and in the VMEM slots:
-    a unit second-minor dim tiles as (1, 128), so a DMA may start at any
-    single row, where the (8, 128) tiling of a 2-D view would demand
-    8-row alignment."""
-    n, t, rpt, in_run, out_run, n_tiles, num_buffers, steps = geometry
+    Rows are viewed as ``(rows, 1, lanes)`` in HBM and in the VMEM slots,
+    the row axis split per :func:`_side_shapes`: a unit second-minor dim
+    tiles as (1, 128), so a DMA may start at any single row, where the
+    (8, 128) tiling of a 2-D view would demand 8-row alignment."""
+    n, t, rpt, in_layout, out_layout, n_tiles, num_buffers, steps = geometry
     row_len = 1 << t
     lead = 1 if batched else 0
     d = x.shape[1 + lead] if x.ndim == 2 + lead else 1
     check_lanes(row_len * d)
-    row_view = (1 << (n - t), 1, row_len * d)
-    if batched:
-        row_view = (x.shape[0],) + row_view
+    lanes = (1, row_len * d)
+    batch = x.shape[:1] if batched else ()
+    views, slots = [], []
+    for layout in (in_layout, out_layout):
+        hbm, slot = _side_shapes(layout, 1 << (n - t), rpt)
+        views.append(batch + hbm + lanes)
+        slots.append(pltpu.VMEM((num_buffers,) + slot + lanes, x.dtype))
     scal, vmem, specs = [], [], []
     for e, sc, vm in zip(epilogue, epi_scalar, epi_vmem):
         if e[0] == "map":
@@ -662,7 +711,7 @@ def _pass_args(x, geometry, batched, epilogue, epi_scalar, epi_vmem):
     if any(e[0] == "bfly" for e in epilogue):
         scratch = [pltpu.VMEM((rpt, row_len * d), x.dtype),
                    pltpu.SemaphoreType.DMA(())]
-    return row_view, d, scal, vmem, specs, scratch
+    return views, slots, d, scal, vmem, specs, scratch
 
 
 def tiled_permute_bwd_tables(x: jax.Array, ct: jax.Array, in_rows, out_rows,
@@ -680,13 +729,13 @@ def tiled_permute_bwd_tables(x: jax.Array, ct: jax.Array, in_rows, out_rows,
     ``x``. Mirrors :func:`tiled_permute_tables` exactly: same epilogue
     signature, same DMA pipeline depth.
     """
-    n, t, rpt, in_run, out_run, n_tiles, num_buffers, steps = geometry
-    row_view, d, scal, vmem, specs, extra = _pass_args(
-        x, geometry, batched, epilogue, epi_scalar, epi_vmem)
-    tile_shape = (rpt, 1, (1 << t) * d)
+    n, t, rpt, in_layout, out_layout, n_tiles, num_buffers, steps = geometry
+    (in_view, out_view), (in_slots, out_slots), d, scal, vmem, specs, \
+        extra = _pass_args(x, geometry, batched, epilogue, epi_scalar,
+                           epi_vmem)
     kern = functools.partial(
-        _tile_bwd_kernel, rpt=rpt, row_len=1 << t, d=d,
-        in_run=in_run, out_run=out_run, batched=batched,
+        _tile_bwd_kernel, rpt=rpt, row_len=1 << t, d=d, n_rows=1 << (n - t),
+        in_layout=in_layout, out_layout=out_layout, batched=batched,
         n_tiles=n_tiles, num_buffers=num_buffers, steps=steps,
         epis=tuple(epilogue), map_fns=tuple(map_fns),
     )
@@ -699,20 +748,20 @@ def tiled_permute_bwd_tables(x: jax.Array, ct: jax.Array, in_rows, out_rows,
         + specs,
         out_specs=pl.BlockSpec(memory_space=_HBM),
         scratch_shapes=[
-            pltpu.VMEM((num_buffers,) + tile_shape, x.dtype),   # x slots
-            pltpu.VMEM((num_buffers,) + tile_shape, x.dtype),   # ct slots
-            pltpu.VMEM((num_buffers,) + tile_shape, x.dtype),   # out slots
+            in_slots,    # x slots
+            out_slots,   # ct slots
+            in_slots,    # out slots: written where the forward read
             pltpu.SemaphoreType.DMA((num_buffers,)),
             pltpu.SemaphoreType.DMA((num_buffers,)),
         ] + extra,
     )
     args = [jnp.asarray(in_rows), jnp.asarray(out_rows), jnp.asarray(ktab),
             jnp.asarray(words)] + scal
-    args += [x.reshape(row_view), ct.reshape(row_view)] + vmem
+    args += [x.reshape(in_view), ct.reshape(out_view)] + vmem
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(row_view, x.dtype),
+        out_shape=jax.ShapeDtypeStruct(in_view, x.dtype),
         interpret=interpret_mode(),
         compiler_params=_compiler_params(len(grid)),
         name="bmmc_tile_bwd",
@@ -735,12 +784,15 @@ def plan_geometry(plan: TilePlan, num_buffers: int = None, *,
     ``num_buffers`` (the DMA pipeline depth) is part of the geometry so
     executables with different buffering never share a cache entry; so
     are the intra-tile steps in use (``plan.intra``, or ``plan.intra_inv``
-    with ``inverse=True`` for the gradient kernel)."""
+    with ``inverse=True`` for the gradient kernel). Each side's layout
+    is its box (``plan.in_box`` / ``out_box``, a tuple of runs) when it
+    has one, else its run of consecutive rows per descriptor."""
     if num_buffers is None:
         num_buffers = default_num_buffers(plan.n_tiles)
     intra = plan.intra_inv if inverse else plan.intra
-    return (plan.n, plan.t, plan.rows_per_tile, plan.in_run, plan.out_run,
-            plan.n_tiles, num_buffers, intra.steps)
+    return (plan.n, plan.t, plan.rows_per_tile, plan.in_box or plan.in_run,
+            plan.out_box or plan.out_run, plan.n_tiles, num_buffers,
+            intra.steps)
 
 
 def plan_tables(plan: TilePlan, *, inverse: bool = False) -> tuple:
@@ -776,13 +828,13 @@ def tiled_permute_tables(x: jax.Array, in_rows, out_rows, ktab, words, *,
     compiled kernel cache key) is independent of B; only the jit retrace,
     not the plan, depends on the batch size.
     """
-    n, t, rpt, in_run, out_run, n_tiles, num_buffers, steps = geometry
-    row_view, d, scal, vmem, specs, extra = _pass_args(
-        x, geometry, batched, epilogue, epi_scalar, epi_vmem)
-    tile_shape = (rpt, 1, (1 << t) * d)
+    n, t, rpt, in_layout, out_layout, n_tiles, num_buffers, steps = geometry
+    (in_view, out_view), (in_slots, out_slots), d, scal, vmem, specs, \
+        extra = _pass_args(x, geometry, batched, epilogue, epi_scalar,
+                           epi_vmem)
     kern = functools.partial(
-        _tile_kernel, rpt=rpt, row_len=1 << t, d=d,
-        in_run=in_run, out_run=out_run, batched=batched,
+        _tile_kernel, rpt=rpt, row_len=1 << t, d=d, n_rows=1 << (n - t),
+        in_layout=in_layout, out_layout=out_layout, batched=batched,
         n_tiles=n_tiles, num_buffers=num_buffers, steps=steps,
         epis=tuple(epilogue), map_fns=tuple(map_fns),
     )
@@ -793,18 +845,18 @@ def tiled_permute_tables(x: jax.Array, in_rows, out_rows, ktab, words, *,
         in_specs=[pl.BlockSpec(memory_space=_HBM)] + specs,
         out_specs=pl.BlockSpec(memory_space=_HBM),
         scratch_shapes=[
-            pltpu.VMEM((num_buffers,) + tile_shape, x.dtype),   # in slots
-            pltpu.VMEM((num_buffers,) + tile_shape, x.dtype),   # out slots
+            in_slots,
+            out_slots,
             pltpu.SemaphoreType.DMA((num_buffers,)),
             pltpu.SemaphoreType.DMA((num_buffers,)),
         ] + extra,
     )
     args = [jnp.asarray(in_rows), jnp.asarray(out_rows), jnp.asarray(ktab),
-            jnp.asarray(words)] + scal + [x.reshape(row_view)] + vmem
+            jnp.asarray(words)] + scal + [x.reshape(in_view)] + vmem
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(row_view, x.dtype),
+        out_shape=jax.ShapeDtypeStruct(out_view, x.dtype),
         interpret=interpret_mode(),
         compiler_params=_compiler_params(len(grid)),
         name="bmmc_tile",

@@ -140,7 +140,18 @@ def class_dispatch(x: jax.Array, bmmc: Bmmc, t: Optional[int],
         tx = modeled_transactions(bmmc, teff, x.dtype.itemsize)
         _ometrics.inc("dma.descriptors", tx["descriptors"])
         _ometrics.inc("model.round_trips", tx["passes"])
+        if got[0] not in ("none", "block", "lane"):
+            count_box_sides(got[1])
     return got
+
+
+def count_box_sides(plans) -> None:
+    """``dma.box_sides{side}``: one count per side of each tiled pass
+    whose rows form a box (one strided descriptor per tile)."""
+    for p in plans:
+        for side, box in (("in", p.in_box), ("out", p.out_box)):
+            if box:
+                _ometrics.inc("dma.box_sides", side=side)
 
 
 def bmmc_permute(x: jax.Array, bmmc: Bmmc, *, t: Optional[int] = None,

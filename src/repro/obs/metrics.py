@@ -18,6 +18,9 @@ Counter vocabulary used by the executor stack (DESIGN.md §12):
   / block / lane / tiled / general) of each dispatched matrix.
 * ``dma.descriptors`` / ``model.round_trips`` — modeled DMA descriptor
   and HBM-round-trip totals of everything dispatched.
+* ``dma.box_sides{side=in|out}`` — tiled-pass sides dispatched whose
+  rows form a box, each copied by one strided descriptor per tile
+  (``TilePlan.in_box`` / ``out_box``).
 * ``dispatch.vjp{kind=...}`` — one count per custom-vjp backward rule
   executed (``perm`` / ``collapsed`` / ``replay`` / ``fused`` /
   ``stage``), i.e. which backward compilation path (DESIGN.md §13) a

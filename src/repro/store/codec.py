@@ -39,7 +39,7 @@ SCHEMA_VERSION = 1
 # Code fingerprint: entries planned by a different planner generation
 # are version-skew misses, never trusted. Bump alongside planner or
 # table-layout changes.
-CODE_VERSION = "plan-v2"   # v2: square tiles (2^t x 2^t rows)
+CODE_VERSION = "plan-v3"   # v3: box sides in ascending slot order
 
 _HEADER_FMT = "<IQ"  # header_len, header_fp
 _PREFIX_LEN = len(MAGIC) + struct.calcsize(_HEADER_FMT)

@@ -16,6 +16,7 @@ import chip_spans  # noqa: E402
 import harness  # noqa: E402
 import xplane  # noqa: E402
 from repro import obs  # noqa: E402
+from repro.combinators import clear_caches  # noqa: E402
 
 
 def _call(t0, spans):
@@ -87,7 +88,9 @@ def test_set_up_and_window_readings_on_a_recorded_trace(tmp_path):
     """The sort cell's entry at 2^8 on the CPU: set-up reports the
     plan spans of its first call and its descriptors; a window traced
     by the benchmark's own loop, telemetry on, yields every per-call
-    span of the call path."""
+    span of the call path. The plan caches start empty, whatever ran
+    before in this process, so the first call plans."""
+    clear_caches()
     c = harness.cell("sort-fwd-20")
     cfg, mix = dict(c["cfg"], n=8), dict(c["mix"], inputs=1)
     calls, sharding = harness.load_module("entries", mix["entry"]).build(
@@ -103,6 +106,7 @@ def test_set_up_and_window_readings_on_a_recorded_trace(tmp_path):
     assert 0 < setup["plan_s"] <= 1.000001 * sum(
         k["s"] for k in setup["plan_kinds"].values())
     assert setup["dma_descriptors_per_call"] > 0
+    assert setup["dma_box_sides_per_call"]["in"] > 0
     assert not obs.enabled() and obs.events() == []
 
     win = harness.Window(calls, xs, mix, 1)

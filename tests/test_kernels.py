@@ -15,6 +15,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core.bmmc import Bmmc
 from repro.core.tiling import plan_bmmc, plan_tiled
+from repro.kernels import bmmc_permute as bp
 from repro.kernels.bmmc_permute import copy_through_vmem, tiled_permute
 from repro.kernels.ops import bmmc_permute, choose_tile, num_passes
 from repro.kernels.ref import bmmc_ref, bmmc_ref_jnp
@@ -125,3 +126,47 @@ def test_dma_run_merging():
     ident_rows = plan_tiled(Bmmc.identity(10), 3)
     assert ident_rows.in_run == ident_rows.rows_per_tile
     assert ident_rows.out_run == ident_rows.rows_per_tile
+
+
+def _row_runs(geometry, plan):
+    """The pass with both sides copied per run of consecutive rows, as
+    the kernel copies a side that is no box."""
+    return geometry[:3] + (plan.in_run, plan.out_run) + geometry[5:]
+
+
+@pytest.mark.parametrize("layout", ["flat", "batched", "planar"])
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32, jnp.bfloat16])
+def test_box_and_row_dma_paths_match_ref(dtype, layout):
+    """Box sides (a BPC with a complement: multi-run boxes on both sides)
+    and sides that are no box (a random BMMC) match ``kernels/ref.py``
+    bit for bit, forward and through the gradient kernel; the BPC also
+    with its sides forced onto the per-run path."""
+    n, t = 10, 4
+    rng = random.Random(11)
+    bpc = Bmmc(Bmmc.random_bpc(n, rng).rows, rng.getrandbits(n))
+    bmmc = Bmmc.random(n, rng)
+    batched = layout == "batched"
+    shape = {"flat": (1 << n,), "batched": (3, 1 << n),
+             "planar": (1 << n, 2)}[layout]
+    x = jnp.asarray(np.random.default_rng(0).integers(
+        -2**15, 2**15, size=shape)).astype(dtype)
+    ct = x[::-1] if not batched else x[:, ::-1]
+    for b in (bpc, bmmc):
+        (plan,) = plan_bmmc(b, t)
+        assert (plan.in_box is not None) == (b is bpc)
+        geoms = [(bp.plan_geometry(plan),
+                  bp.plan_geometry(plan, inverse=True))]
+        if b is bpc:
+            assert plan.out_box is not None
+            geoms.append((_row_runs(geoms[0][0], plan),
+                          _row_runs(geoms[0][1], plan)))
+        want = np.asarray(bmmc_ref(x, b, batched=batched))
+        want_ct = np.asarray(bmmc_ref(ct, b.inverse(), batched=batched))
+        for fwd, bwd in geoms:
+            got = bp.tiled_permute_tables(x, *bp.plan_tables(plan),
+                                          geometry=fwd, batched=batched)
+            assert np.asarray(got).tobytes() == want.tobytes()
+            got = bp.tiled_permute_bwd_tables(
+                x, ct, *bp.plan_tables(plan, inverse=True), geometry=bwd,
+                batched=batched)
+            assert np.asarray(got).tobytes() == want_ct.tobytes()

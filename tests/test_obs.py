@@ -124,6 +124,36 @@ def test_sort_counters_match_program_cost():
 
 
 @pytest.mark.tier1
+def test_box_side_counter_matches_plans():
+    """A cold sort call counts ``dma.box_sides`` per side: exactly the
+    tiled-pass sides of its plans whose rows form a box."""
+    from repro.combinators import execute as ex
+    from repro.combinators.ir import Perm
+    from repro.combinators.optimize import FusedStage
+    from repro.kernels.ops import class_plan
+    clear_caches()
+    n = 8
+    t = choose_tile(n, 4, 1)
+    f = compile_expr(sort_expr(n), engine="pallas")
+    plans = []
+    for s in f.clustered_program(n, t):
+        if isinstance(s, FusedStage):
+            plans += ex._fused_plan_cached(s, t)[0]
+        elif isinstance(s, Perm):
+            kernel, payload = class_plan(s.bmmc, t)
+            if kernel in ("tiled", "general", "general2"):
+                plans += payload
+    want = {"in": sum(p.in_box is not None for p in plans),
+            "out": sum(p.out_box is not None for p in plans)}
+    assert want["in"] and want["out"]
+    obs.enable(sync=True)
+    jax.block_until_ready(f(_payload((1 << n,), 3)))
+    got = {side: obs.counter_value("dma.box_sides", side=side)
+           for side in want}
+    assert got == want
+
+
+@pytest.mark.tier1
 def test_report_renders_after_execution():
     clear_caches()
     n = 7

@@ -351,12 +351,16 @@ def test_memos_surface_in_cache_stats_and_reset():
 
 
 @pytest.mark.tier1
-def test_version_skew_is_miss_then_heals(tmp_store):
+@pytest.mark.parametrize("code", [None, "plan-v2"])
+def test_version_skew_is_miss_then_heals(tmp_store, code):
+    """An entry of another schema, or of the planner generation before
+    box sides took ascending slot order (``plan-v2``), is a skew miss
+    that the rebuild overwrites."""
     n = 6
     b, t, key = _plan_key(n)
     ops._class_plan_cached(b.rows, b.c, t)
     data = tmp_store.read_bytes(key)
-    tmp_store.write_bytes(key, inject._skewed_entry(data))
+    tmp_store.write_bytes(key, inject._skewed_entry(data, code))
     base = store.stats()
     store.class_plan_through(
         b.rows, b.c, t, lambda: ops._build_class_plan(b.rows, b.c, t))

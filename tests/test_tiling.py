@@ -7,7 +7,10 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core.bmmc import Bmmc
 from repro.core.tiling import (naive_write_runs, plan_bmmc, plan_general,
-                               plan_tiled)
+                               plan_stats, plan_stats_general, plan_tiled,
+                               stats_bmmc)
+from repro.kernels import bmmc_permute as bp
+from repro.kernels.ops import choose_tile
 
 
 @given(st.integers(6, 12), st.integers(0, 10**6), st.integers(2, 4))
@@ -157,3 +160,92 @@ def test_transaction_model_tiled_vs_naive():
     assert in_bytes >= (1 << t) * 4 and out_bytes >= (1 << t) * 4
     # identity: naive already coalesced
     assert naive_write_runs(Bmmc.identity(n), seg_elems=1 << t) == 1.0
+
+
+def _assert_ascending_box(rows):
+    """The side's rows form a box enumerated in ascending slot order:
+    every tile's first row is clear on the box's bits, and slot r adds
+    the bits of r spread over them lowest first (offsets strictly
+    increase, 2^p of them within p bits)."""
+    off = rows ^ rows[:, :1]
+    mask = int(np.bitwise_or.reduce(off, axis=None))
+    assert bin(mask).count("1") == rows.shape[1].bit_length() - 1
+    assert not (rows[:, 0] & mask).any()
+    assert (np.diff(off, axis=1) > 0).all()
+
+
+def _box_sides(p):
+    return [rows for rows, box in ((p.in_rows, p.in_box),
+                                   (p.out_rows, p.out_box)) if box]
+
+
+@pytest.mark.parametrize("n", range(10, 25))
+@pytest.mark.parametrize("kind", ["bitrev", "bpc"])
+def test_bpc_sides_are_ascending_boxes(kind, n):
+    """Both sides of every bit-reverse and BPC pass (complement
+    included) form a box, enumerated in ascending slot order."""
+    rng = random.Random(n)
+    b = (Bmmc.bit_reverse(n) if kind == "bitrev" else
+         Bmmc(Bmmc.random_bpc(n, rng).rows, rng.getrandbits(n)))
+    (p,) = plan_bmmc(b, choose_tile(n, 4))
+    assert p.in_box and p.out_box
+    for rows in _box_sides(p):
+        _assert_ascending_box(rows)
+
+
+def test_sort_box_sides_are_ascending():
+    """Every box side of the 2^20 sort's tiled passes enumerates its
+    row bits in ascending slot order; every input side of a classic
+    pass is a box."""
+    from repro.combinators import execute as ex
+    from repro.combinators.ir import Perm
+    from repro.combinators.optimize import FusedStage
+    from repro.combinators.sort import compiled_sort
+    from repro.kernels.ops import class_plan
+    n = 20
+    t = choose_tile(n, 4)
+    plans = []
+    for s in compiled_sort(n, engine="pallas").clustered_program(n, t):
+        if isinstance(s, FusedStage):
+            plans += ex._fused_plan_cached(s, t)[0]
+        elif isinstance(s, Perm):
+            kernel, payload = class_plan(s.bmmc, t)
+            if kernel in ("tiled", "general", "general2"):
+                plans += payload
+    out_boxes = sum(p.out_box is not None for p in plans)
+    assert out_boxes >= 13
+    for p in plans:
+        if p.row_cols:
+            assert p.in_box
+        for rows in _box_sides(p):
+            _assert_ascending_box(rows)
+
+
+@pytest.mark.parametrize("n,t", [(8, 3), (10, 4), (12, 6), (12, 5), (9, 5),
+                                 (14, 6)])
+def test_descriptor_counts_agree(n, t):
+    """``TilePlan.dma_descriptors`` equals the analytic ``PlanStats``
+    count and what the kernel issues: one descriptor per tile for a
+    box side, one per run of consecutive rows for any other."""
+    rng = random.Random(n * 7 + t)
+    cases = [Bmmc.bit_reverse(n)]
+    cases += [Bmmc(Bmmc.random_bpc(n, rng).rows, rng.getrandbits(n))
+              for _ in range(3)]
+    cases += [Bmmc.random(n, rng) for _ in range(4)]
+    n_rows = 1 << (n - t)
+    for b in cases:
+        for build, stats in ((plan_tiled, plan_stats),
+                             (plan_general, plan_stats_general)):
+            p, s = build(b, t), stats(b, t)
+            if p is None:
+                assert s is None
+                continue
+            assert (s.in_box, s.out_box, s.in_run, s.out_run) == \
+                (p.in_box, p.out_box, p.in_run, p.out_run)
+            geom = bp.plan_geometry(p)
+            issued = sum(bp._Side(layout, p.rows_per_tile, n_rows, None).count
+                         for layout in geom[3:5])
+            assert p.dma_descriptors() == s.dma_descriptors() \
+                == p.n_tiles * issued
+        assert sum(s.dma_descriptors() for s in stats_bmmc(b, t)) == sum(
+            p.dma_descriptors() for p in plan_bmmc(b, t))

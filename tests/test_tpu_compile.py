@@ -69,12 +69,22 @@ def _has_kernel(text: str) -> bool:
     return "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("name", ["bit_reverse", "bmmc"])
+# runs per box side (input, output); None where a side is no box
+_BOX_RUNS = {"bit_reverse": (1, 1), "bpc": (5, 3), "bmmc": (None, None)}
+
+
+@pytest.mark.parametrize("name", sorted(_BOX_RUNS))
 def test_tiled_pass_compiles(chip, name):
-    b = (Bmmc.bit_reverse(N) if name == "bit_reverse"
-         else Bmmc.random(N, random.Random(7)))
+    """The paper suite's passes: bit-reverse and the random BPC of
+    ``paper_fig9.cases`` take strided box descriptors on both sides, the
+    random BMMC per-row descriptors."""
+    b = {"bit_reverse": lambda: Bmmc.bit_reverse(N),
+         "bpc": lambda: Bmmc.random_bpc(N, random.Random(42)),
+         "bmmc": lambda: Bmmc.random(N, random.Random(7))}[name]()
     t = choose_tile(N, 4)
     (plan,) = plan_bmmc(b, t)
+    assert tuple(box and len(box) for box in (plan.in_box, plan.out_box)) \
+        == _BOX_RUNS[name]
     fn = functools.partial(bp.tiled_permute_tables,
                            geometry=bp.plan_geometry(plan))
     tables = bp.plan_tables(plan)
@@ -124,6 +134,8 @@ def test_fused_sort_cmp_cluster_compiles(chip):
     t = choose_tile(n, 4)
     fs = _cluster(compiled_sort(n, engine="pallas").clustered_program(n, t),
                   CmpHalves, 3)
+    plans, _ = ex._fused_plan_cached(fs, t)
+    assert plans[0].in_box and plans[0].out_box
     fn, tables, scal, vmem = _fused_pass(fs, t, jnp.float32)
     text = chip(lambda x: fn(x, *tables, epi_scalar=scal, epi_vmem=vmem),
                 ((1 << n,), jnp.float32))
