@@ -15,8 +15,9 @@ recording:
       ``jax.value_and_grad``;
   (c) a batched permutation of 64 x 2^16 f32.
 
-``--chips 4`` runs only ``distributed_bmmc`` over a binary mesh of the
-four chips of one host: bit-reverse and a random BMMC of 2^26 int32.
+``--chips 4`` runs only ``make_distributed_bmmc`` over a binary mesh of
+the four chips of one host: bit-reverse and a random BMMC of 2^26
+int32, each local round on the Pallas kernels.
 
 Every result is compared with a host-side numpy evaluation
 (``out[A i ^ c] = x[i]``, ``np.sort``, ``np.fft``); gradients are also
@@ -300,23 +301,28 @@ def phase_batched(n: int, batch: int) -> None:
 
 def phase_sharded(n: int, s: int = 2) -> None:
     import jax
-    from jax.sharding import NamedSharding, PartitionSpec
+    from jax.sharding import NamedSharding
+    from repro import obs
     from repro.core.bmmc import Bmmc
-    from repro.core.distributed import binary_mesh, distributed_bmmc
+    from repro.core.distributed import (binary_mesh, make_distributed_bmmc,
+                                        shard_spec)
 
     check(len(jax.devices()) >= 1 << s,
           f"the reshard needs {1 << s} devices, found {len(jax.devices())}")
     mesh = binary_mesh(s)
     host = np.random.default_rng(SEED + 4).integers(
         -2**31, 2**31 - 1, size=1 << n, dtype=np.int32)
-    # the leading axis split over every mesh axis, as distributed_bmmc
-    # lays it out, so no call below moves the input between devices
-    x = jax.device_put(host, NamedSharding(mesh,
-                                           PartitionSpec(mesh.axis_names)))
+    # the leading axis split over every mesh axis, as the sharded
+    # program takes it, so no call below moves the input between devices
+    x = jax.device_put(host, NamedSharding(mesh, shard_spec(s)))
     for name, b in (("bit-reverse", Bmmc.bit_reverse(n)),
                     ("random-bmmc", Bmmc.random(n, random.Random(SEED)))):
-        fn = lambda v: distributed_bmmc(v, b, s, mesh)  # noqa: E731
+        obs.reset()
+        fn = make_distributed_bmmc(b, s, mesh)
         out, cold = timed(fn, x)
+        kernels = {k: int(v) for k, v in obs.kernel_counts().items()}
+        check(bool(kernels) and "ref" not in kernels,
+              f"d/{name}: local rounds dispatched {kernels}")
         want = np_permute(host, b)
         shards = out.addressable_shards
         devices = {sh.device for sh in shards}
@@ -331,7 +337,7 @@ def phase_sharded(n: int, s: int = 2) -> None:
               f"d/{name}: output differs from out[A i ^ c] = x[i]")
         report(f"d/{name}", shape=list(out.shape), dtype="int32",
                shards={str(sh.device): [sh.index[0].start, sh.index[0].stop]
-                       for sh in shards},
+                       for sh in shards}, kernels=kernels,
                cold_s=cold, warm_s=warm_seconds(fn, x))
 
 

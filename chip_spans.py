@@ -11,14 +11,18 @@ the seed), then:
 1. set-up: the first and second call of each program with the
    library's telemetry on. It reports the union of the ``plan.*``
    spans (``plan_s``: planning paid on plan-cache misses), each kind's
-   count and time, the first calls' time beyond the second less the
-   compiles in them (as the benchmark's ``trace_plan_s``), and the
-   ``dma.descriptors`` and ``dma.box_sides{side}`` counters over the
-   number of programs;
+   count and time (``plan.dist`` for a sharded cell), the first calls'
+   time beyond the second less the compiles in them (as the
+   benchmark's ``trace_plan_s``), and the ``dma.descriptors`` and
+   ``dma.box_sides{side}`` counters over the number of programs; for a
+   sharded cell also the ``dist.bytes{kind}`` counter over the number
+   of programs, beside the least bytes a call must move between chips
+   (the ``collective_roofline`` reader's count);
 2. window: the benchmark's own closed loop and traced sub-window
    (``harness.Window``), with telemetry on all through it. Over the
    traced calls it reports ``host_call_us`` (as the benchmark's reader
-   does), the mean per call of every ``repro.*`` span and of JAX's
+   does), the mean per call of every ``repro.*`` span (``dist.call``
+   for a sharded cell) and of JAX's
    outermost ``PjitFunction(<fn>)`` dispatch events, and the longest
    idle gaps of the device, each named by the innermost ``bench.*`` or
    ``repro.*`` span in its middle.
@@ -90,6 +94,8 @@ def warm_up(calls, x, clog) -> dict:
         descriptors = obs.counter_total("dma.descriptors")
         box_sides = {side: obs.counter_value("dma.box_sides", side=side)
                      for side in ("in", "out")}
+        dist_bytes = {kind: obs.counter_value("dist.bytes", kind=kind)
+                      for kind in ("all_to_all", "ppermute")}
     finally:
         obs.disable()
         obs.reset()
@@ -98,11 +104,15 @@ def warm_up(calls, x, clog) -> dict:
     for e in plans:
         count, total = kinds.get(e["name"], (0, 0.0))
         kinds[e["name"]] = (count + 1, total + e["dur"] / 1e6)
-    return {"trace_plan_s": first - second - compile_s, "plan_s": union_s(plans),
-            "plan_kinds": {k: {"count": c, "s": s} for k, (c, s) in sorted(kinds.items())},
-            "dma_descriptors_per_call": descriptors / len(calls),
-            "dma_box_sides_per_call": {k: v / len(calls)
-                                       for k, v in box_sides.items()}}
+    out = {"trace_plan_s": first - second - compile_s, "plan_s": union_s(plans),
+           "plan_kinds": {k: {"count": c, "s": s} for k, (c, s) in sorted(kinds.items())},
+           "dma_descriptors_per_call": descriptors / len(calls),
+           "dma_box_sides_per_call": {k: v / len(calls)
+                                      for k, v in box_sides.items()}}
+    if any(dist_bytes.values()):
+        out["dist_bytes_per_call"] = {k: v / len(calls)
+                                      for k, v in dist_bytes.items()}
+    return out
 
 
 def readings(trace: dict, extra: list, enqueue_s: list) -> dict:
@@ -153,6 +163,9 @@ def probe(workload: str, seed: int, seconds: float) -> dict:
             cfg, mix, devices[:chips])
         xs = harness.make_inputs(cfg, mix, seed, sharding)
         setup = warm_up(calls, xs[0], clog)
+        if "dist_bytes_per_call" in setup:
+            setup["least_dist_bytes_per_call"] = harness.load_module(
+                "metrics", "collective_roofline").least_bytes_per_call(cfg, mix)
     finally:
         jax.monitoring.unregister_event_time_span_listener(clog)
     win = harness.Window(calls, xs, mix, seed)

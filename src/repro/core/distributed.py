@@ -12,21 +12,30 @@ factors any global BMMC into a short sequence of *rounds*:
 * ``ExchangeRound``— swap the top-k local index bits with the low-k shard
                      bits: one (sub-axis) all_to_all.
 
-Construction (generalizing paper §5.2 to the sharded setting): with the
-F2 decomposition A = U L P and L = R U' R (R = bit reversal),
+Construction (generalizing paper §5.2 to the sharded setting): a matrix
+with ``A_sl = 0`` is shard-separable (one local round, one permute). A
+bit permutation, with or without a complement, lowers to [permute,
+local, exchange(k), local, permute], k the bits that change sides, its
+complement split between the last local and permute rounds. Any other
+matrix takes the F2 decomposition A = U L P and L = R U' R (R = bit
+reversal),
 
     A  =  U  ∘  R  ∘  U'  ∘  (R P)
 
 where U, U' are shard-*separable* (upper-triangular => shard-out depends
-only on shard-in) and R, RP are bit permutations, each of which lowers to
-[permute, local, exchange(k), local, permute]. After fusing adjacent rounds
-the worst case is **2 exchange rounds + 2 permute rounds + O(1) local
-rounds** — the sharded analogue of the paper's two-pass theorem.
+only on shard-in) and R, RP are bit permutations. Adjacent rounds of one
+type are fused, and identities dropped, until nothing merges: at most
+**2 exchange rounds** — the sharded analogue of the paper's two-pass
+theorem.
 
-Every plan is verified *offline* by composing the rounds back into a global
-BMMC (`plan_to_bmmc(plan) == A`); the executor (`run_plan`, shard_map over
-a binary sub-axis mesh) is validated on fake multi-device CPU meshes in
-tests/test_distributed.py.
+Every plan is verified by composing the rounds back into a global BMMC
+(`plan_to_bmmc(plan) == A`). The executor (`make_distributed_bmmc`) is
+one jitted shard_map over a binary sub-axis mesh: every local round runs
+the class-dispatched Pallas kernels of `kernels/ops.py` on the chip's
+shard, and a complement that depends on the shard runs one kernel whose
+tables, one set per shard, are chosen by the shard's mesh index
+(`ops.bmmc_permute_complements`). It is validated on fake multi-device
+CPU meshes in tests/test_distributed.py.
 """
 from __future__ import annotations
 
@@ -34,8 +43,8 @@ import dataclasses
 import functools
 from typing import List, Optional, Union
 
-import numpy as np
-
+from ..obs import metrics as _ometrics
+from ..obs import trace as _otrace
 from . import f2
 from .bmmc import Bmmc
 
@@ -180,53 +189,76 @@ def _bp_rounds(b: Bmmc, s: int) -> List[Round]:
     return rounds
 
 
+def _merge(prev: Round, r: Round, nl: int, s: int) -> Optional[Round]:
+    """``r ∘ prev`` as one round when both are local or both permute
+    rounds; None otherwise."""
+    if isinstance(r, LocalRound) and isinstance(prev, LocalRound):
+        rows = f2.matmul(r.rows, prev.rows)
+        # combine: y = R2 (R1 x ^ L1 sigma ^ c1) ^ L2 sigma ^ c2
+        ls_cols = []
+        for bit in range(s):
+            col_prev = sum(((prev.ls_rows[i] >> bit) & 1) << i
+                           for i in range(nl))
+            col_new = f2.matvec(r.rows, col_prev)
+            col_new ^= sum(((r.ls_rows[i] >> bit) & 1) << i
+                           for i in range(nl))
+            ls_cols.append(col_new)
+        ls = tuple(sum(((ls_cols[bit] >> i) & 1) << bit
+                       for bit in range(s)) for i in range(nl))
+        c = f2.matvec(r.rows, prev.c) ^ r.c
+        return LocalRound(nl, rows, c, ls)
+    if isinstance(r, PermuteRound) and isinstance(prev, PermuteRound):
+        return PermuteRound(s, f2.matmul(r.rows, prev.rows),
+                            f2.matvec(r.rows, prev.c) ^ r.c)
+    return None
+
+
+def _is_identity(r: Round, nl: int, s: int) -> bool:
+    if isinstance(r, LocalRound):
+        return (r.rows == f2.identity(nl) and r.c == 0
+                and not any(r.ls_rows))
+    if isinstance(r, PermuteRound):
+        return r.rows == f2.identity(s) and r.c == 0
+    return r.k == 0
+
+
 def _fuse(plan: List[Round], n: int, s: int) -> List[Round]:
-    """Merge adjacent same-type rounds; drop identities."""
+    """Merge adjacent same-type rounds and drop identities, until
+    nothing merges: dropping an identity can make two rounds of one
+    type adjacent."""
     nl = n - s
-    out: List[Round] = []
-    for r in plan:
-        if out and isinstance(r, LocalRound) and isinstance(out[-1], LocalRound):
-            prev = out[-1]
-            rows = f2.matmul(r.rows, prev.rows)
-            # combine: y = R2 (R1 x ^ L1 sigma ^ c1) ^ L2 sigma ^ c2
-            ls_cols = []
-            for bit in range(s):
-                col_prev = sum(((prev.ls_rows[i] >> bit) & 1) << i
-                               for i in range(nl))
-                col_new = f2.matvec(r.rows, col_prev)
-                col_new ^= sum(((r.ls_rows[i] >> bit) & 1) << i
-                               for i in range(nl))
-                ls_cols.append(col_new)
-            ls = tuple(sum(((ls_cols[bit] >> i) & 1) << bit
-                           for bit in range(s)) for i in range(nl))
-            c = f2.matvec(r.rows, prev.c) ^ r.c
-            out[-1] = LocalRound(nl, rows, c, ls)
-        elif out and isinstance(r, PermuteRound) and isinstance(out[-1], PermuteRound):
-            prev = out[-1]
-            out[-1] = PermuteRound(s, f2.matmul(r.rows, prev.rows),
-                                   f2.matvec(r.rows, prev.c) ^ r.c)
-        else:
-            out.append(r)
-    cleaned = []
-    for r in out:
-        if isinstance(r, LocalRound) and r.rows == f2.identity(nl) \
-                and r.c == 0 and all(v == 0 for v in r.ls_rows):
-            continue
-        if isinstance(r, PermuteRound) and r.rows == f2.identity(s) and r.c == 0:
-            continue
-        if isinstance(r, ExchangeRound) and r.k == 0:
-            continue
-        cleaned.append(r)
-    return cleaned
+    while True:
+        out: List[Round] = []
+        for r in plan:
+            if _is_identity(r, nl, s):
+                continue
+            merged = _merge(out[-1], r, nl, s) if out else None
+            if merged is None:
+                out.append(r)
+            else:
+                out[-1] = merged
+        if out == plan:
+            return out
+        plan = out
 
 
 def make_plan(bmmc: Bmmc, s: int) -> List[Round]:
     """Factor a global BMMC into rounds for 2^s leading-axis shards."""
     n = bmmc.n
     assert 0 < s < n
+    nl = n - s
     a_ll, a_ls, a_sl, a_ss = _split_blocks(bmmc, s)
     if all(v == 0 for v in a_sl):
         plan = _separable_rounds(bmmc, s)
+    elif bmmc.perm() is not None:
+        # BP(C): one exchange of the bits that change sides. The
+        # complement splits into a local and a shard part, folded into
+        # the last local and permute rounds (the local part commutes
+        # with the last round, which moves shard bits only).
+        *plan, last = _bp_rounds(Bmmc(bmmc.rows, 0), s)
+        plan += [LocalRound(nl, f2.identity(nl), bmmc.c & ((1 << nl) - 1),
+                            (0,) * nl),
+                 last, PermuteRound(s, f2.identity(s), bmmc.c >> nl)]
     else:
         u, l, p = f2.ulp(bmmc.rows)
         r = f2.reversal(n)
@@ -239,7 +271,8 @@ def make_plan(bmmc: Bmmc, s: int) -> List[Round]:
         plan += _separable_rounds(Bmmc(u, bmmc.c), s)
     plan = _fuse(plan, n, s)
     got = plan_to_bmmc(plan, n, s)
-    assert got.rows == bmmc.rows and got.c == bmmc.c, "plan verification failed"
+    if got.rows != bmmc.rows or got.c != bmmc.c:
+        raise AssertionError("plan verification failed")
     return plan
 
 
@@ -252,60 +285,71 @@ def plan_cost(plan: List[Round]) -> dict:
     }
 
 
+def plan_bytes(plan: List[Round], s: int, nbytes: int) -> dict:
+    """Bytes one call of ``plan`` sends between chips, summed over the
+    chips, for an array of ``nbytes``: an exchange of k bits keeps
+    2^-k of each shard in place and sends the rest (``all_to_all``); a
+    shard permute sends each shard it moves (``ppermute``)."""
+    out = {"all_to_all": 0, "ppermute": 0}
+    for r in plan:
+        if isinstance(r, ExchangeRound):
+            out["all_to_all"] += nbytes - (nbytes >> r.k)
+        elif isinstance(r, PermuteRound):
+            moved = sum(f2.matvec(r.rows, g) ^ r.c != g for g in range(1 << s))
+            out["ppermute"] += moved * (nbytes >> s)
+    return out
+
+
 # ---------------------------------------------------------------------------
 # Executor (shard_map over a binary sub-axis mesh)
 # ---------------------------------------------------------------------------
 
-def binary_mesh(s: int):
-    """Mesh of 2^s devices as s binary axes sb{s-1}..sb0 (msb first)."""
+def binary_mesh(s: int, devices=None):
+    """Mesh of 2^s devices (the first 2^s of ``jax.devices()`` unless
+    given) as s binary axes sb{s-1}..sb0 (msb first)."""
     import jax
     names = tuple(f"sb{m}" for m in reversed(range(s)))
     return jax.make_mesh((2,) * s, names,
-                         axis_types=(jax.sharding.AxisType.Auto,) * s)
+                         axis_types=(jax.sharding.AxisType.Auto,) * s,
+                         devices=devices)
 
 
-def _parity_bits(v, rows, nbits: int):
-    """``rows . v`` over F2, elementwise on a uint32 array (bit i of the
-    result is the parity of ``v & rows[i]``)."""
-    import jax
-    import jax.numpy as jnp
-    out = jnp.zeros_like(v)
-    for i, row in enumerate(rows[:nbits]):
-        bit = jax.lax.population_count(v & jnp.uint32(row)) & 1
-        out = out | (bit << i)
-    return out
-
-
-def _shard_spec(s: int, ndim: int):
+def shard_spec(s: int):
     """The leading axis split over all s binary mesh axes, msb first."""
     from jax.sharding import PartitionSpec as P
-    names_msb = tuple(f"sb{m}" for m in reversed(range(s)))
-    return P(names_msb) if ndim == 1 else P(names_msb, None)
+    return P(tuple(f"sb{m}" for m in reversed(range(s))))
 
 
 @functools.lru_cache(maxsize=64)
-def _plan_executable(plan: tuple, s: int, nl: int, ndim: int, mesh):
-    """One jitted shard_map program per (plan, shard geometry, mesh)."""
+def _plan_executable(plan: tuple, s: int, mesh):
+    """One jitted shard_map program per (plan, mesh). Local rounds run
+    the class-dispatched Pallas kernels of ``ops``; a complement that
+    depends on the shard takes the tables of the shard's own index."""
     import jax
     import jax.numpy as jnp
+    from ..kernels import ops
 
     names_msb = tuple(f"sb{m}" for m in reversed(range(s)))
 
     def shard_fn(xs):
-        sig = jnp.zeros((), jnp.uint32)
+        nbytes = xs.size * xs.dtype.itemsize << s
+        for r in plan:
+            kind = ("local" if isinstance(r, LocalRound) else
+                    "permute" if isinstance(r, PermuteRound) else "exchange")
+            _ometrics.inc("dist.rounds", kind=kind)
+        for kind, v in plan_bytes(list(plan), s, nbytes).items():
+            _ometrics.inc("dist.bytes", v, kind=kind)
+        shard = jnp.zeros((), jnp.int32)
         for m in range(s):
-            sig = sig | (jax.lax.axis_index(f"sb{m}").astype(jnp.uint32) << m)
-
+            shard = shard | (jax.lax.axis_index(f"sb{m}").astype(jnp.int32)
+                             << m)
+        nl = xs.shape[0].bit_length() - 1
         for r in plan:
             if isinstance(r, LocalRound):
-                inv = f2.inverse(r.rows)
-                # dynamic complement: c_eff = c ^ (ls . shard);
-                # src[y] = inv.(y ^ c_eff) = inv.y ^ inv.c_eff
-                c_eff = jnp.uint32(r.c) ^ _parity_bits(
-                    sig, [row & ((1 << s) - 1) for row in r.ls_rows], nl)
-                y = jax.lax.iota(jnp.uint32, 1 << nl)
-                src = _parity_bits(y, inv, nl) ^ _parity_bits(c_eff, inv, nl)
-                xs = jnp.take(xs, src.astype(jnp.int32), axis=0)
+                # c_eff = c ^ (ls . shard), one complement per shard
+                cs = tuple(r.c ^ f2.matvec(r.ls_rows, g)
+                           for g in range(1 << s))
+                xs = ops.bmmc_permute_complements(xs, r.rows, cs, shard)
             elif isinstance(r, PermuteRound):
                 pairs = [(g, f2.matvec(r.rows, g) ^ r.c)
                          for g in range(1 << s)]
@@ -320,23 +364,39 @@ def _plan_executable(plan: tuple, s: int, nl: int, ndim: int, mesh):
                 xs = xs2.reshape((1 << nl,) + tail)
         return xs
 
-    spec = _shard_spec(s, ndim)
+    spec = shard_spec(s)
+    # check_vma=False: the kernels' pallas_call outputs carry no
+    # varying-axes annotation
     return jax.jit(jax.shard_map(shard_fn, mesh=mesh, in_specs=spec,
-                                 out_specs=spec))
+                                 out_specs=spec, check_vma=False))
 
 
-def run_plan(x, plan: List[Round], s: int, mesh=None):
-    """Apply a distributed BMMC plan to ``x`` (shape (2^n,) or (2^n, d))."""
-    import jax
-    from jax.sharding import NamedSharding
+@functools.lru_cache(maxsize=256)
+def _cached_plan(rows: tuple, c: int, s: int) -> tuple:
+    with _otrace.span("plan.dist", n=len(rows), s=s):
+        return tuple(make_plan(Bmmc(rows, c), s))
 
+
+def make_distributed_bmmc(bmmc: Bmmc, s: int, mesh=None):
+    """A function applying ``bmmc`` to an array of shape (2^n,) or
+    (2^n, d) whose leading axis is split over the 2^s devices of
+    ``mesh`` (default :func:`binary_mesh`), as :func:`shard_spec` says.
+    The first call builds the plan, once per (matrix, s), as
+    ``ops.make_bmmc_permute`` plans at its first trace; the function is
+    one jitted ``shard_map`` program, traced once per shape and dtype."""
     mesh = mesh or binary_mesh(s)
-    nl = int(np.log2(x.shape[0])) - s
-    fn = _plan_executable(tuple(plan), s, nl, x.ndim, mesh)
-    x = jax.device_put(x, NamedSharding(mesh, _shard_spec(s, x.ndim)))
-    return fn(x)
+    fn = None
+
+    def call(x):
+        nonlocal fn
+        with _otrace.span("dist.call", n=bmmc.n, s=s):
+            if fn is None:
+                fn = _plan_executable(_cached_plan(bmmc.rows, bmmc.c, s),
+                                      s, mesh)
+            return fn(x)
+    return call
 
 
 def distributed_bmmc(x, bmmc: Bmmc, s: int, mesh=None):
     """End-to-end: plan + execute a BMMC over a 2^s-sharded array."""
-    return run_plan(x, make_plan(bmmc, s), s, mesh)
+    return make_distributed_bmmc(bmmc, s, mesh)(x)

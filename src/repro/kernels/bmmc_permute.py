@@ -795,6 +795,14 @@ def plan_geometry(plan: TilePlan, num_buffers: int = None, *,
             intra.steps)
 
 
+def geometry_descriptors(geometry: tuple) -> int:
+    """HBM DMA descriptors that one pass of :func:`plan_geometry`
+    ``geometry`` issues: per tile, each side's :class:`_Side` count."""
+    n, t, rpt, in_layout, out_layout, n_tiles = geometry[:6]
+    return n_tiles * sum(_Side(layout, rpt, 1 << (n - t), None).count
+                         for layout in (in_layout, out_layout))
+
+
 def plan_tables(plan: TilePlan, *, inverse: bool = False) -> tuple:
     """The runtime tables of one pass, in kernel order: ``(in_rows,
     out_rows, ktab, words)``."""

@@ -33,8 +33,11 @@ from ..core.tiling import (class_stats, copy_descriptors, dispatch_kernel,
 from ..obs import metrics as _ometrics
 from ..obs import trace as _otrace
 from . import ref as _ref
-from .bmmc_permute import (block_permute, check_lanes, lane_permute,
-                           tiled_permute)
+from .bmmc_permute import (block_geometry, block_permute,
+                           block_permute_tables, check_lanes,
+                           geometry_descriptors, lane_geometry, lane_permute,
+                           lane_permute_tables, plan_geometry, plan_tables,
+                           tiled_permute, tiled_permute_tables)
 
 # VMEM working-set budget for one tile buffer. The double-buffered pipeline
 # holds 2 * num_buffers tile-sized slots (in + out, default num_buffers=2);
@@ -135,22 +138,36 @@ def class_dispatch(x: jax.Array, bmmc: Bmmc, t: Optional[int],
     with _otrace.span("kernel.dispatch", n=bmmc.n, t=teff) as sargs:
         got = class_plan(bmmc, teff)
         sargs["kernel"] = got[0]
-        _ometrics.inc("dispatch.kernel", kernel=got[0])
-        _ometrics.inc("dispatch.class", cls=bmmc.bmmc_class(teff))
         tx = modeled_transactions(bmmc, teff, x.dtype.itemsize)
-        _ometrics.inc("dma.descriptors", tx["descriptors"])
-        _ometrics.inc("model.round_trips", tx["passes"])
-        if got[0] not in ("none", "block", "lane"):
-            count_box_sides(got[1])
+        tiled = got[0] not in ("none", "block", "lane")
+        record_dispatch(got[0], bmmc.bmmc_class(teff), tx["descriptors"],
+                        tx["passes"], got[1] if tiled else ())
     return got
 
 
-def count_box_sides(plans) -> None:
+def record_dispatch(kernel: str, cls: str, descriptors: int,
+                    round_trips: int, plans=(), geometries=()) -> None:
+    """The counters of one dispatch decision: ``dispatch.kernel``,
+    ``dispatch.class``, the modeled ``dma.descriptors`` and
+    ``model.round_trips``, and the box sides of its tiled passes, given
+    as plans or as their :func:`plan_geometry`."""
+    _ometrics.inc("dispatch.kernel", kernel=kernel)
+    _ometrics.inc("dispatch.class", cls=cls)
+    _ometrics.inc("dma.descriptors", descriptors)
+    _ometrics.inc("model.round_trips", round_trips)
+    count_box_sides(plans, geometries)
+
+
+def count_box_sides(plans=(), geometries=()) -> None:
     """``dma.box_sides{side}``: one count per side of each tiled pass
-    whose rows form a box (one strided descriptor per tile)."""
-    for p in plans:
-        for side, box in (("in", p.in_box), ("out", p.out_box)):
-            if box:
+    whose rows form a box (one strided descriptor per tile). A plan's
+    box is ``in_box`` / ``out_box``; a geometry's, a side whose layout
+    is a tuple of runs."""
+    layouts = [(p.in_box, p.out_box) for p in plans]
+    layouts += [g[3:5] for g in geometries]
+    for pair in layouts:
+        for side, layout in zip(("in", "out"), pair):
+            if isinstance(layout, tuple) and layout:
                 _ometrics.inc("dma.box_sides", side=side)
 
 
@@ -188,6 +205,79 @@ def bmmc_permute(x: jax.Array, bmmc: Bmmc, *, t: Optional[int] = None,
         return lane_permute(x, payload, batched=batched)
     for plan in payload:
         x = tiled_permute(x, plan, batched=batched)
+    return x
+
+
+def _shared_geometry(passes) -> tuple:
+    """One :func:`plan_geometry` that serves every plan of ``passes``
+    (one pass of one matrix under different complements, which move
+    row ids but not the tile structure). A side keeps its box where
+    every plan has that box, else takes the shortest run any plan has:
+    runs are powers of two, so a shorter run is also a run of every
+    longer one. An intra-tile step runs where any plan needs it; with
+    its identity parameters it moves nothing."""
+    geoms = [plan_geometry(p) for p in passes]
+    g0 = geoms[0]
+    assert all(g[:3] + g[5:7] == g0[:3] + g0[5:7] for g in geoms), geoms
+    sides = tuple(
+        g0[k] if len({g[k] for g in geoms}) == 1 else min(runs)
+        for k, runs in ((3, [p.in_run for p in passes]),
+                        (4, [p.out_run for p in passes])))
+    steps = tuple(any(on) for on in zip(*(g[7] for g in geoms)))
+    return g0[:3] + sides + g0[5:7] + (steps,)
+
+
+def _pick(tables, index) -> jax.Array:
+    """``tables[index]`` of stacked equal-shape tables, ``index`` traced."""
+    stack = jnp.asarray(np.stack([np.asarray(v) for v in tables]))
+    return jax.lax.dynamic_index_in_dim(stack, index, 0, keepdims=False)
+
+
+def bmmc_permute_complements(x: jax.Array, rows: tuple, cs: tuple,
+                             index: jax.Array) -> jax.Array:
+    """``out[A i ^ cs[index]] = x[i]`` with ``index`` traced: one
+    kernel whose tables, one set per complement, are stacked and
+    chosen by ``index`` on the device. A sharded program passes its
+    shard's index, so every shard runs the same compiled kernel.
+
+    The complements share the class dispatch of ``rows`` except where
+    ``rows`` is the identity (none, block, lane or tiled by the bits
+    of ``c``); those take the tiled pass. The ``dispatch.*`` and
+    ``dma.*`` counters record the one kernel."""
+    if len(set(cs)) == 1:
+        return bmmc_permute(x, Bmmc(rows, cs[0]))
+    n = len(rows)
+    d = x.shape[1] if x.ndim == 2 else 1
+    t = choose_tile(n, x.dtype.itemsize, d)
+    if t is None:
+        check_lanes(d)
+        return jax.lax.switch(index, [functools.partial(
+            _ref.bmmc_ref, bmmc=Bmmc(rows, c)) for c in cs], x)
+    got = [class_plan(Bmmc(rows, c), t) for c in cs]
+    kernel = got[0][0]
+    if any(k != kernel for k, _ in got):
+        kernel, got = "tiled", [("tiled", _plans_cached(rows, c, t))
+                                for c in cs]
+    payloads = [p for _, p in got]
+    geoms = [] if kernel in ("block", "lane") else [
+        _shared_geometry(passes) for passes in zip(*payloads)]
+    if _otrace._state.enabled:
+        descriptors = (sum(geometry_descriptors(g) for g in geoms) if geoms
+                       else payloads[0].dma_descriptors())
+        record_dispatch(kernel, Bmmc(rows, cs[0]).bmmc_class(t),
+                        descriptors, max(len(geoms), 1), geometries=geoms)
+    if kernel == "block":
+        return block_permute_tables(
+            x, _pick([p.src_rows for p in payloads], index),
+            geometry=block_geometry(payloads[0]))
+    if kernel == "lane":
+        return lane_permute_tables(
+            x, _pick([p.src_lane for p in payloads], index),
+            geometry=lane_geometry(payloads[0]))
+    for k, geometry in enumerate(geoms):
+        tables = zip(*(plan_tables(passes[k]) for passes in payloads))
+        x = tiled_permute_tables(x, *(_pick(v, index) for v in tables),
+                                 geometry=geometry)
     return x
 
 

@@ -21,6 +21,10 @@ Counter vocabulary used by the executor stack (DESIGN.md §12):
 * ``dma.box_sides{side=in|out}`` — tiled-pass sides dispatched whose
   rows form a box, each copied by one strided descriptor per tile
   (``TilePlan.in_box`` / ``out_box``).
+* ``dist.rounds{kind=local|permute|exchange}`` /
+  ``dist.bytes{kind=all_to_all|ppermute}`` — the rounds of a sharded
+  program (``core/distributed.py``) and the bytes its plan sends
+  between chips per call, summed over the chips; at trace time.
 * ``dispatch.vjp{kind=...}`` — one count per custom-vjp backward rule
   executed (``perm`` / ``collapsed`` / ``replay`` / ``fused`` /
   ``stage``), i.e. which backward compilation path (DESIGN.md §13) a

@@ -23,12 +23,13 @@ Span taxonomy (DESIGN.md §12.2):
   ``entry.apply`` (the chosen dispatch up to its return: the
   custom-vjp boundary, then ``program.call`` > ``program.lookup`` (the
   whole-program executable's cache lookup) + ``program.enqueue`` (the
-  executable's call up to its return));
+  executable's call up to its return)); ``dist.call`` (a sharded
+  call's host path, ``core/distributed.py``);
 * at trace time — ``stage.*`` (one primitive/fused stage as the
   executor walks the program) > ``kernel.dispatch`` /
   ``kernel.fused`` (one class-dispatch decision);
 * on a plan-cache miss only — ``plan.lower``, ``plan.cluster``,
-  ``plan.bwd``, ``plan.fused``, ``plan.class``.
+  ``plan.bwd``, ``plan.fused``, ``plan.class``, ``plan.dist``.
 
 Drivers add ``serve.*`` / ``train.step`` roots.
 

@@ -153,6 +153,65 @@ def test_box_side_counter_matches_plans():
     assert got == want
 
 
+def _stacked_case(kind: str) -> tuple:
+    """(rows, complements) of one stacked-table pass: a random BPC or
+    a random BMMC at 2^10, or the local round of a sharded 2^11 plan
+    whose shards' complements give the tiled passes different runs."""
+    from repro.core import distributed as D, f2
+    from repro.kernels import ops
+    from repro.kernels.bmmc_permute import plan_geometry
+    rng = random.Random(5)
+    cs = (0, 0b11 << 7, 0b101, (0b11 << 7) | 0b101)
+    if kind != "shard-round":
+        draw = Bmmc.random_bpc if kind == "bpc" else Bmmc.random
+        return draw(10, rng).rows, cs
+    t = choose_tile(9, 4, 1)
+    for _ in range(100):
+        for r in D.make_plan(Bmmc.random(11, rng), 2):
+            if not (isinstance(r, D.LocalRound) and any(r.ls_rows)):
+                continue
+            cs = tuple(r.c ^ f2.matvec(r.ls_rows, g) for g in range(4))
+            got = [ops.class_plan(Bmmc(r.rows, c), t) for c in cs]
+            if got[0][0] not in ("block", "lane") and len(
+                    {tuple(plan_geometry(q)[:7] for q in pay)
+                     for _, pay in got}) > 1:
+                return r.rows, cs
+    raise AssertionError("no local round with per-shard geometries")
+
+
+@pytest.mark.tier1
+@pytest.mark.parametrize("kind", ["bpc", "bmmc", "shard-round"])
+def test_stacked_descriptor_counter_matches_kernel(monkeypatch, kind):
+    """A pass whose tables are stacked per complement (a sharded local
+    round) counts ``dma.descriptors`` and ``dma.box_sides`` as the
+    kernels it launches issue them: per tile, each side's ``_Side``
+    count under the geometry the kernel is given."""
+    from repro.kernels import bmmc_permute as bp
+    from repro.kernels import ops
+    rows, cs = _stacked_case(kind)
+    launched = []
+    real = ops.tiled_permute_tables
+
+    def spy(x, *tables, geometry, **kw):
+        launched.append(geometry)
+        return real(x, *tables, geometry=geometry, **kw)
+
+    monkeypatch.setattr(ops, "tiled_permute_tables", spy)
+    obs.enable(sync=True)
+    fn = jax.jit(lambda v, i: ops.bmmc_permute_complements(v, rows, cs, i))
+    jax.block_until_ready(fn(_payload((1 << len(rows),), 4), jnp.int32(1)))
+    assert launched
+    issued = sum(g[5] * sum(bp._Side(layout, g[2], 1 << (g[0] - g[1]),
+                                     None).count for layout in g[3:5])
+                 for g in launched)
+    assert obs.counter_total("dma.descriptors") == issued
+    boxes = {side: sum(isinstance(g[k], tuple) for g in launched)
+             for k, side in ((3, "in"), (4, "out"))}
+    assert {side: obs.counter_value("dma.box_sides", side=side)
+            for side in boxes} == boxes
+    assert obs.counter_total("model.round_trips") == len(launched)
+
+
 @pytest.mark.tier1
 def test_report_renders_after_execution():
     clear_caches()

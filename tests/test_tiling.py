@@ -246,6 +246,6 @@ def test_descriptor_counts_agree(n, t):
             issued = sum(bp._Side(layout, p.rows_per_tile, n_rows, None).count
                          for layout in geom[3:5])
             assert p.dma_descriptors() == s.dma_descriptors() \
-                == p.n_tiles * issued
+                == p.n_tiles * issued == bp.geometry_descriptors(geom)
         assert sum(s.dma_descriptors() for s in stats_bmmc(b, t)) == sum(
             p.dma_descriptors() for p in plan_bmmc(b, t))

@@ -1,7 +1,7 @@
 """Compile-only rehearsals: every kernel class compiled for a described
 TPU v5e at real sizes (2^24 int32 for the permutation suite, the 2^20
-sort's fused compare cluster, a fused planar FFT butterfly cluster and
-the gradient kernel). Nothing runs — the chip's compiler only has to
+sort's fused compare cluster, a fused planar FFT butterfly cluster, the
+gradient kernel, and the four-chip reshard of 2^26 int32). Nothing runs — the chip's compiler only has to
 accept each kernel, which the Pallas interpreter cannot show.
 
 The topology is described inside a module fixture (never at import:
@@ -9,19 +9,23 @@ only one process may load the TPU library), and the test steers the
 package's single backend decision to the compiled path itself.
 """
 import functools
+import json
 import random
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, SingleDeviceSharding
 
 from repro.combinators import execute as ex
 from repro.combinators.fft import compiled_fft
 from repro.combinators.ir import Bfly, CmpHalves
 from repro.combinators.optimize import FusedStage
 from repro.combinators.sort import compiled_sort
+from repro.core import distributed
 from repro.core.bmmc import Bmmc
 from repro.core.tiling import plan_block, plan_bmmc, plan_lane
 from repro.kernels import bmmc_permute as bp
@@ -163,3 +167,29 @@ def test_gradient_kernel_with_cmp_compiles(chip):
     text = chip(lambda v, c: fn(v, c, *tables, epi_scalar=scal,
                                 epi_vmem=vmem), x, x)
     assert _has_kernel(text)
+
+
+RESHARD = json.loads((Path(__file__).resolve().parent.parent / "bench" / "traffic"
+                      / "reshard_round_robin.json").read_text())
+
+
+@pytest.mark.parametrize("name", [op["name"] for op in RESHARD["ops"]])
+def test_reshard_compiles_for_four_chips(monkeypatch, topo, name):
+    """The reshard cell's sharded programs at 2^26 int32 over the
+    described 2x2 host: one compiled Pallas kernel per local round, one
+    all-to-all per exchange and one collective permute per shard
+    permute, and no gather."""
+    monkeypatch.setattr(bp, "interpret_mode", lambda: False)
+    op = next(o for o in RESHARD["ops"] if o["name"] == name)
+    plan = distributed.make_plan(Bmmc(tuple(op["rows"]), op["c"]), 2)
+    mesh = distributed.binary_mesh(2, topo.devices)
+    x = jax.ShapeDtypeStruct((1 << 26,), jnp.int32, sharding=NamedSharding(
+        mesh, distributed.shard_spec(2)))
+    text = distributed._plan_executable(
+        tuple(plan), 2, mesh).lower(x).compile().as_text()
+    cost = distributed.plan_cost(plan)
+    assert text.count("tpu_custom_call") == cost["local"]
+    assert len(re.findall(r"all-to-all\(", text)) == cost["exchange"]
+    assert len(re.findall(r"collective-permute(-start)?\(", text)) \
+        == cost["permute"]
+    assert not re.search(r" gather\(", text)
