@@ -14,7 +14,9 @@ the seed), then:
    count and time (``plan.dist`` for a sharded cell), the first calls'
    time beyond the second less the compiles in them (as the
    benchmark's ``trace_plan_s``), and the ``dma.descriptors`` and
-   ``dma.box_sides{side}`` counters over the number of programs; for a
+   ``dma.box_sides{side}`` counters over the number of programs, the
+   intra-tile schedule's ``intra.xor_moves``, ``intra.maps`` and
+   ``intra.transposed`` counters of each program's call; for a
    sharded cell also the ``dist.bytes{kind}`` counter over the number
    of programs, beside the least bytes a call must move between chips
    (the ``collective_roofline`` reader's count);
@@ -74,6 +76,9 @@ def union_s(events) -> float:
     return total / 1e6
 
 
+INTRA = ("xor_moves", "maps", "transposed")
+
+
 def warm_up(calls, x, clog) -> dict:
     """First and second call of each program with telemetry on."""
     import jax
@@ -81,15 +86,19 @@ def warm_up(calls, x, clog) -> dict:
     obs.reset()
     obs.enable(sync=False)
     first = second = 0.0
+    intra = {}
     wall0 = time.time()
     try:
-        for _, fn in calls:
+        for name, fn in calls:
+            before = {k: obs.counter_total(f"intra.{k}") for k in INTRA}
             t0 = time.perf_counter()
             jax.block_until_ready(fn(x))
             t1 = time.perf_counter()
             jax.block_until_ready(fn(x))
             first += t1 - t0
             second += time.perf_counter() - t1
+            intra[name] = {k: obs.counter_total(f"intra.{k}") - before[k]
+                           for k in INTRA}
         plans = [e for e in obs.events() if e["name"].startswith("plan.")]
         descriptors = obs.counter_total("dma.descriptors")
         box_sides = {side: obs.counter_value("dma.box_sides", side=side)
@@ -108,7 +117,8 @@ def warm_up(calls, x, clog) -> dict:
            "plan_kinds": {k: {"count": c, "s": s} for k, (c, s) in sorted(kinds.items())},
            "dma_descriptors_per_call": descriptors / len(calls),
            "dma_box_sides_per_call": {k: v / len(calls)
-                                      for k, v in box_sides.items()}}
+                                      for k, v in box_sides.items()},
+           "intra_per_call": intra}
     if any(dist_bytes.values()):
         out["dist_bytes_per_call"] = {k: v / len(calls)
                                       for k, v in dist_bytes.items()}

@@ -84,8 +84,9 @@ from ..core.tiling import (compute_tables, pairing_vector, plan_bmmc,
                            plan_general)
 from ..kernels import ref as _ref
 from ..kernels.bmmc_permute import (block_geometry, block_permute_tables,
-                                    lane_geometry, lane_permute_tables,
-                                    plan_geometry, plan_tables,
+                                    item_width, lane_geometry,
+                                    lane_permute_tables, plan_geometry,
+                                    plan_tables,
                                     tiled_permute_bwd_tables,
                                     tiled_permute_tables)
 from .ir import Bfly, CmpHalves, Expr, Map, Perm
@@ -188,9 +189,10 @@ def _pallas_engine(x: jax.Array, bmmc: Bmmc, *, t: Optional[int] = None,
     if kernel == "lane":
         run = _lane_executable(lane_geometry(payload), batched)
         return run(x, payload.src_lane)
+    d = item_width(x, batched)
     for plan in payload:
-        run = _geom_executable(plan_geometry(plan), batched)
-        x = run(x, *plan_tables(plan))
+        run = _geom_executable(plan_geometry(plan, d=d), batched)
+        x = run(x, *plan_tables(plan, d=d))
     return x
 
 
@@ -324,12 +326,13 @@ def _fused_pallas(x: jax.Array, fs: FusedStage, t: int, *,
     permutation."""
     plans, entries = _fused_plan_cached(fs, t)
     plan = plans[0]
+    d = item_width(x, batched)
     sig, scal, vmem, map_fns = _fused_kernel_args(entries, x.dtype)
-    run = _geom_executable(plan_geometry(plan), batched, sig, map_fns)
-    x = run(x, *plan_tables(plan), epi_scalar=scal, epi_vmem=vmem)
+    run = _geom_executable(plan_geometry(plan, d=d), batched, sig, map_fns)
+    x = run(x, *plan_tables(plan, d=d), epi_scalar=scal, epi_vmem=vmem)
     for plan in plans[1:]:
-        run = _geom_executable(plan_geometry(plan), batched)
-        x = run(x, *plan_tables(plan))
+        run = _geom_executable(plan_geometry(plan, d=d), batched)
+        x = run(x, *plan_tables(plan, d=d))
     return x
 
 
@@ -344,7 +347,9 @@ def _fused_forward(x, fs, engine, batched):
                 _ometrics.inc("model.round_trips", len(plans))
                 _ometrics.inc("dma.descriptors",
                               sum(p.dma_descriptors() for p in plans))
-                ops.count_box_sides(plans)
+                d = item_width(x, batched)
+                ops.count_tiled_passes(
+                    [plan_geometry(p, d=d) for p in plans])
                 with _otrace.span("kernel.fused", stages=len(fs.stages),
                                   passes=len(plans), t=t):
                     return _fused_pallas(x, fs, t, batched=batched)
@@ -804,15 +809,16 @@ def _fused_bwd_pallas(fs, t, batched, x, ct):
     """One-kernel cluster backward: undo the trailing plain passes, then
     dispatch the gradient megakernel over the forward's own plan."""
     plans, entries, extra = _fused_bwd_kernel_plan(fs, t)
+    d = item_width(ct, batched)
     for inv_plans in reversed(extra):
         for p in inv_plans:
-            run = _geom_executable(plan_geometry(p), batched)
-            ct = run(ct, *plan_tables(p))
+            run = _geom_executable(plan_geometry(p, d=d), batched)
+            ct = run(ct, *plan_tables(p, d=d))
     plan = plans[0]
     sig, scal, vmem, map_fns = _fused_kernel_args(entries, x.dtype)
-    run = _geom_bwd_executable(plan_geometry(plan, inverse=True), batched,
-                               sig, map_fns)
-    return run(x, ct, *plan_tables(plan, inverse=True),
+    run = _geom_bwd_executable(plan_geometry(plan, inverse=True, d=d),
+                               batched, sig, map_fns)
+    return run(x, ct, *plan_tables(plan, inverse=True, d=d),
                epi_scalar=scal, epi_vmem=vmem)
 
 
@@ -863,8 +869,10 @@ def _fused_bwd_impl(fs, engine, batched, x, ct):
                     + p0.n_tiles * p0.side_descriptors()[0]
                     + sum(p.dma_descriptors()
                           for ip in extra for p in ip))
-                ops.count_box_sides(
-                    (p0,) + tuple(p for ip in extra for p in ip))
+                d = item_width(ct, batched)
+                ops.count_tiled_passes(
+                    [plan_geometry(p0, inverse=True, d=d)]
+                    + [plan_geometry(p, d=d) for ip in extra for p in ip])
                 with _otrace.span("kernel.fused_bwd", stages=len(fs.stages),
                                   passes=rt, t=t):
                     return _fused_bwd_pallas(fs, t, batched, x, ct)

@@ -32,8 +32,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import random
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -228,19 +227,36 @@ class TilePlan(_Descriptors):
         return self
 
     @functools.cached_property
-    def intra(self) -> "IntraMap":
-        """The kernel's factorization of the ``src0``/``xor_low`` gather."""
+    def intra_options(self) -> tuple:
+        """The kernel's factorizations of the ``src0``/``xor_low``
+        gather, one per orientation (:func:`intra_options`)."""
         m, ks = _intra_affine(self.src0, self.xor_low)
-        return factor_intra(m, ks, self.rows_per_tile, self.row_len)
+        return intra_options(m, ks, self.rows_per_tile, self.row_len)
 
     @functools.cached_property
-    def intra_inv(self) -> "IntraMap":
-        """Factorization of the inverse gather (the gradient kernel's
+    def intra_inv_options(self) -> tuple:
+        """Factorizations of the inverse gather (the gradient kernel's
         un-permute of the cotangent tile)."""
         m, ks = _intra_affine(self.src0, self.xor_low)
         minv = f2.inverse(m)
-        return factor_intra(minv, [f2.matvec(minv, k) for k in ks],
-                            self.rows_per_tile, self.row_len)
+        return intra_options(minv, [f2.matvec(minv, k) for k in ks],
+                             self.rows_per_tile, self.row_len)
+
+    def intra_map(self, d: int = 1, *, inverse: bool = False) -> "IntraMap":
+        """The cheapest factorization a tile of ``d``-wide items can run
+        (:func:`best_intra`), of the gather or of its inverse."""
+        return best_intra(self.intra_inv_options if inverse
+                          else self.intra_options, d)
+
+    @property
+    def intra(self) -> "IntraMap":
+        """:meth:`intra_map` for 1-wide items."""
+        return self.intra_map()
+
+    @property
+    def intra_inv(self) -> "IntraMap":
+        """:meth:`intra_map` of the inverse gather for 1-wide items."""
+        return self.intra_map(inverse=True)
 
 
 # ---------------------------------------------------------------------------
@@ -259,22 +275,84 @@ class TilePlan(_Descriptors):
 #   4. row shear     z[r, c] = w[r ^ F0 c, c]
 #
 # With M = [[A, B], [C, D0]] (row/lane blocks): pick F0 so that
-# D = D0 ^ C F0 is invertible (F0 = 0 when D0 already is), then
-# F1 = B' D^-1, S = A ^ F1 C, E = D^-1 C with B' = B ^ A F0. The
-# per-tile constant rides step 2: (kr, kc) = M1 K_g.
+# D = D0 ^ C F0 is invertible, then F1 = B' D^-1, S = A ^ F1 C,
+# E = D^-1 C with B' = B ^ A F0. The per-tile constant rides step 2:
+# (kr, kc) = M1 K_g. A shear is one XOR move (two rolls, two selects)
+# per row or lane bit whose word is nonzero, so the kernel emits only
+# those; a map is a one-hot matmul per byte plane.
+#
+# The F0 candidates: 0 when D0 is invertible; A^-1 B when A is (then
+# F1 = 0, and D is the Schur complement, invertible as M is); and a
+# greedy pick of unit words, one for each of the t - rank(D0) lane
+# bits D0 lacks. Setting word j of F0 to v adds (column j of C) v^T
+# to D, which raises its rank exactly when that column lies outside
+# D's column space and v outside its row space; as [C D0] has full
+# row rank, some column of C qualifies while D is singular, and some
+# unit v always does, so the pick never fails.
+#
+# A square tile of 1-wide items may also be transposed first: the tile
+# then gathers by T M, constants T K_g, where T swaps the row and lane
+# fields. A bit permutation that moves bits between rows and lanes
+# (bit-reverse, most BPCs) has a singular D0 in one orientation and a
+# nearly block-diagonal M in the other. The plan keeps each
+# orientation's cheapest candidate; the kernel runs the cheaper one
+# its items allow (``best_intra``).
 # ---------------------------------------------------------------------------
+
+class IntraSteps(NamedTuple):
+    """The static schedule of one intra-tile gather (part of the
+    executable key): whether the tile is transposed first, the row bits
+    whose first row-shear word is nonzero, whether the row and lane maps
+    do work, the lane bits whose lane-shear word is nonzero, and the row
+    bits of the second row shear."""
+
+    transpose: bool = False
+    f1: tuple = ()
+    row_map: bool = False
+    lane_map: bool = False
+    e: tuple = ()
+    f0: tuple = ()
+
+    @property
+    def xor_moves(self) -> int:
+        return len(self.f1) + len(self.e) + len(self.f0)
+
+    @property
+    def maps(self) -> int:
+        return int(self.row_map) + int(self.lane_map)
+
+    def cost(self) -> tuple:
+        """Fewest XOR moves, then fewest maps, then no transpose."""
+        return self.xor_moves, self.maps, self.transpose
+
+    @staticmethod
+    def union(schedules) -> "IntraSteps":
+        """One schedule that serves every one of ``schedules`` (stacked
+        per-shard tables of one orientation): a move or map runs where
+        any of them needs it; with zero words it moves nothing."""
+        schedules = list(schedules)
+        (transpose,) = {s.transpose for s in schedules}
+
+        def bits(name):
+            return tuple(sorted(set().union(
+                *(getattr(s, name) for s in schedules))))
+        return IntraSteps(transpose, bits("f1"),
+                          any(s.row_map for s in schedules),
+                          any(s.lane_map for s in schedules), bits("e"),
+                          bits("f0"))
+
 
 @dataclasses.dataclass(frozen=True)
 class IntraMap:
     """Kernel tables of one intra-tile affine gather.
 
-    ``steps`` flags which of (row shear 1, row map, lane map, lane shear,
-    row shear 2) do work — part of the executable key. ``words`` holds
-    their parameters as ``f1 (p) | sinv (p) | dcol (t) | e (t) | f0
-    (p)`` bit masks; ``ktab[g]`` packs tile g's ``S^-1 kr << t | kc``.
+    ``steps`` (:class:`IntraSteps`) is the static schedule. ``words``
+    holds the steps' parameters as ``f1 (p) | sinv (p) | dcol (t) | e
+    (t) | f0 (p)`` bit masks; ``ktab[g]`` packs tile g's
+    ``S^-1 kr << t | kc``.
     """
 
-    steps: tuple
+    steps: IntraSteps
     words: np.ndarray     # (3p + 2t,) int32
     ktab: np.ndarray      # (n_tiles,) int32
 
@@ -301,24 +379,53 @@ def _madd(a, b) -> tuple:
     return tuple(x ^ y for x, y in zip(a, b))
 
 
-def factor_intra(m: tuple, ks: list, rows: int, row_len: int) -> IntraMap:
-    """Factor the gather ``z[u] = w[M u ^ K_g]`` on a (rows, row_len)
-    tile into the kernel's four steps (see the block comment above)."""
-    t = row_len.bit_length() - 1
-    p = rows.bit_length() - 1
+def _bits_set(words) -> tuple:
+    return tuple(j for j, w in enumerate(words) if w)
+
+
+def _greedy_f0(c: tuple, d0: tuple, order, t: int) -> tuple:
+    """Unit words of F0, one per lane bit ``D0`` lacks, that make
+    ``D0 ^ C F0`` invertible; row bits tried in ``order``."""
+    f0 = [0] * len(order)
+    d, rank = d0, f2.rank(d0)
+    for j in order:
+        if rank == t:
+            break
+        for q in range(t):
+            nd = tuple(r ^ (((ci >> j) & 1) << q) for r, ci in zip(d, c))
+            if f2.rank(nd) > rank:
+                f0[j], d, rank = 1 << q, nd, rank + 1
+                break
+    assert rank == t, "[C D0] has full row rank"
+    return tuple(f0)
+
+
+def _f0_candidates(a, b, c, d0, p: int, t: int) -> list:
+    """The F0 candidates of the block comment above."""
+    out = []
+    if f2.is_invertible(d0):
+        out.append((0,) * p)
+    if f2.is_invertible(a):
+        out.append(f2.matmul(f2.inverse(a), b))
+    out += [_greedy_f0(c, d0, order, t)
+            for order in (range(p), range(p - 1, -1, -1))]
+    return out
+
+
+def _blocks(m: tuple, p: int, t: int) -> tuple:
+    """``(A, B, C, D0)``: the row/lane blocks of ``M``."""
     tmask, pmask = (1 << t) - 1, (1 << p) - 1
-    a = tuple((m[t + i] >> t) & pmask for i in range(p))
-    b = tuple(m[t + i] & tmask for i in range(p))
-    c = tuple(m[i] >> t for i in range(t))
-    d0 = tuple(m[i] & tmask for i in range(t))
-    if not f2.is_invertible(m):
-        raise ValueError("intra-tile gather is not a bijection")
-    f0 = (0,) * p
-    rng = random.Random(0)
-    while not f2.is_invertible(_madd(d0, f2.matmul(c, f0))):
-        # [C D0] has full row rank, so a random F0 lands on an
-        # invertible D with probability > 1/4 per draw
-        f0 = tuple(rng.getrandbits(t) for _ in range(p))
+    return (tuple((m[t + i] >> t) & pmask for i in range(p)),
+            tuple(m[t + i] & tmask for i in range(p)),
+            tuple(m[i] >> t for i in range(t)),
+            tuple(m[i] & tmask for i in range(t)))
+
+
+def _factor(m: tuple, ks: list, p: int, t: int, f0: tuple,
+            transpose: bool) -> IntraMap:
+    """The steps of one orientation's gather ``(M, [K_g])`` for one F0."""
+    tmask = (1 << t) - 1
+    a, b, c, d0 = _blocks(m, p, t)
     d = _madd(d0, f2.matmul(c, f0))
     dinv = f2.inverse(d)
     f1 = f2.matmul(_madd(b, f2.matmul(a, f0)), dinv)
@@ -334,10 +441,44 @@ def factor_intra(m: tuple, ks: list, rows: int, row_len: int) -> IntraMap:
         list(f1) + [f2.column(sinv, i) for i in range(p)]
         + [f2.column(d, i) for i in range(t)] + list(e) + list(f0),
         dtype=np.int32)
-    steps = (any(f1), s != f2.identity(p) or bool((ktab >> t).any()),
-             d != f2.identity(t) or bool((ktab & tmask).any()),
-             any(e), any(f0))
+    steps = IntraSteps(
+        transpose, _bits_set(f1),
+        s != f2.identity(p) or bool((ktab >> t).any()),
+        d != f2.identity(t) or bool((ktab & tmask).any()),
+        _bits_set(e), _bits_set(f0))
     return IntraMap(steps=steps, words=words, ktab=ktab)
+
+
+def intra_options(m: tuple, ks: list, rows: int, row_len: int) -> tuple:
+    """The cheapest factorization of the gather ``z[u] = w[M u ^ K_g]``
+    on a (rows, row_len) tile in each orientation: the direct one, and
+    for a square tile the transposed one (see the block comment above)."""
+    t = row_len.bit_length() - 1
+    p = rows.bit_length() - 1
+    if not f2.is_invertible(m):
+        raise ValueError("intra-tile gather is not a bijection")
+    orients = [(False, m, ks)]
+    if p == t:
+        full = (1 << 2 * t) - 1
+
+        def swap(v: int) -> int:
+            return ((v << t) | (v >> t)) & full
+        orients.append((True, tuple(m[(i + t) % (2 * t)]
+                                    for i in range(2 * t)),
+                        [swap(k) for k in ks]))
+    out = []
+    for transpose, mo, ko in orients:
+        out.append(min((_factor(mo, ko, p, t, f0, transpose)
+                        for f0 in _f0_candidates(*_blocks(mo, p, t), p, t)),
+                       key=lambda im: im.steps.cost()))
+    return tuple(out)
+
+
+def best_intra(options, d: int = 1) -> IntraMap:
+    """The cheapest of ``options`` (:meth:`IntraSteps.cost`) that a tile
+    of ``d``-wide items can run: only 1-wide items transpose."""
+    return min((im for im in options if d == 1 or not im.steps.transpose),
+               key=lambda im: im.steps.cost())
 
 
 def _square_up(tb: list, deficit: int) -> tuple:

@@ -63,6 +63,13 @@ def interpret_mode() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def item_width(x: jax.Array, batched: bool = False) -> int:
+    """``d``: the trailing width of each element of ``x``, a (2^n,) or
+    (2^n, d) array (a leading batch axis when ``batched``)."""
+    lead = 1 if batched else 0
+    return x.shape[1 + lead] if x.ndim == 2 + lead else 1
+
+
 def check_lanes(lanes: int) -> None:
     """Refuse a kernel row narrower than one vreg on the chip."""
     if lanes < MIN_LANES and not interpret_mode():
@@ -187,25 +194,27 @@ class _TileIndex:
                 b = self.lane_xor(b, i)
         return _from_bits(b, x.dtype)
 
-    def permute(self, x: jax.Array, steps: tuple, words, kt) -> jax.Array:
+    def permute(self, x: jax.Array, steps, words, kt) -> jax.Array:
         """The intra-tile gather of :class:`repro.core.tiling.IntraMap`:
-        ``words`` is its SMEM parameter table, ``kt`` the tile's packed
-        constant."""
+        ``steps`` its static :class:`~repro.core.tiling.IntraSteps`,
+        ``words`` its SMEM parameter table, ``kt`` the tile's packed
+        constant. Only the XOR moves ``steps`` names are emitted."""
         if not any(steps):
             return x
         p, t, d = self.p, self.t, self.d
         b = _to_bits(x)
         nbytes = x.dtype.itemsize
+        if steps.transpose:
+            b = b.T
 
-        def row_shear(b, off):
-            for j in range(p):
+        def row_shear(b, bits, off):
+            for j in bits:
                 m = _parity(self.c & words[off + j], t) == 1
                 b = jnp.where(m, self.row_xor(b, j), b)
             return b
 
-        if steps[0]:
-            b = row_shear(b, 0)
-        if steps[1]:
+        b = row_shear(b, steps.f1, 0)
+        if steps.row_map:
             s = jax.lax.broadcasted_iota(jnp.int32, (1, self.rows), 1)
             src = jnp.zeros_like(s) ^ (kt >> t)
             for i in range(p):
@@ -213,7 +222,7 @@ class _TileIndex:
             q = jax.lax.broadcasted_iota(jnp.int32, (self.rows, self.rows),
                                          0) == src
             b = _onehot_apply(b, q.astype(jnp.bfloat16), True, nbytes)
-        if steps[2]:
+        if steps.lane_map:
             src = jnp.zeros_like(self.c) ^ (kt & (self.row_len - 1))
             for i in range(t):
                 src = src ^ jnp.where(((self.c >> i) & 1) == 1,
@@ -222,12 +231,10 @@ class _TileIndex:
             lanes = self.row_len * d
             q = jax.lax.broadcasted_iota(jnp.int32, (lanes, lanes), 0) == src
             b = _onehot_apply(b, q.astype(jnp.bfloat16), False, nbytes)
-        if steps[3]:
-            for i in range(t):
-                m = _parity(self.r & words[2 * p + t + i], p) == 1
-                b = jnp.where(m, self.lane_xor(b, i), b)
-        if steps[4]:
-            b = row_shear(b, 2 * p + 2 * t)
+        for i in steps.e:
+            m = _parity(self.r & words[2 * p + t + i], p) == 1
+            b = jnp.where(m, self.lane_xor(b, i), b)
+        b = row_shear(b, steps.f0, 2 * p + 2 * t)
         return _from_bits(b, x.dtype)
 
     def hi_mask(self, hi_row, hi_lane, base) -> jax.Array:
@@ -679,9 +686,11 @@ def _pass_args(x, geometry, batched, epilogue, epi_scalar, epi_vmem):
     (8, 128) tiling of a 2-D view would demand 8-row alignment."""
     n, t, rpt, in_layout, out_layout, n_tiles, num_buffers, steps = geometry
     row_len = 1 << t
-    lead = 1 if batched else 0
-    d = x.shape[1 + lead] if x.ndim == 2 + lead else 1
+    d = item_width(x, batched)
     check_lanes(row_len * d)
+    if steps.transpose and d != 1:
+        raise ValueError(f"a tile of {d}-wide items cannot transpose; plan "
+                         f"its geometry with d={d}")
     lanes = (1, row_len * d)
     batch = x.shape[:1] if batched else ()
     views, slots = [], []
@@ -775,7 +784,7 @@ def default_num_buffers(n_tiles: int) -> int:
 
 
 def plan_geometry(plan: TilePlan, num_buffers: int = None, *,
-                  inverse: bool = False) -> tuple:
+                  inverse: bool = False, d: int = 1) -> tuple:
     """The hashable tile geometry of a plan — everything that shapes the
     kernel *except* the per-stage index tables. Two plans with equal
     geometry can share one compiled kernel executable (tables are runtime
@@ -783,13 +792,14 @@ def plan_geometry(plan: TilePlan, num_buffers: int = None, *,
     amortize trace/compile cost across the stages of a fused program.
     ``num_buffers`` (the DMA pipeline depth) is part of the geometry so
     executables with different buffering never share a cache entry; so
-    are the intra-tile steps in use (``plan.intra``, or ``plan.intra_inv``
-    with ``inverse=True`` for the gradient kernel). Each side's layout
+    is the intra-tile schedule (``plan.intra_map(d)``, of the inverse
+    gather with ``inverse=True`` for the gradient kernel: a tile of
+    ``d``-wide items transposes only when ``d == 1``). Each side's layout
     is its box (``plan.in_box`` / ``out_box``, a tuple of runs) when it
     has one, else its run of consecutive rows per descriptor."""
     if num_buffers is None:
         num_buffers = default_num_buffers(plan.n_tiles)
-    intra = plan.intra_inv if inverse else plan.intra
+    intra = plan.intra_map(d, inverse=inverse)
     return (plan.n, plan.t, plan.rows_per_tile, plan.in_box or plan.in_run,
             plan.out_box or plan.out_run, plan.n_tiles, num_buffers,
             intra.steps)
@@ -803,10 +813,11 @@ def geometry_descriptors(geometry: tuple) -> int:
                          for layout in (in_layout, out_layout))
 
 
-def plan_tables(plan: TilePlan, *, inverse: bool = False) -> tuple:
+def plan_tables(plan: TilePlan, *, inverse: bool = False, d: int = 1) -> tuple:
     """The runtime tables of one pass, in kernel order: ``(in_rows,
-    out_rows, ktab, words)``."""
-    intra = plan.intra_inv if inverse else plan.intra
+    out_rows, ktab, words)``, for the :func:`plan_geometry` of the same
+    ``inverse`` and ``d``."""
+    intra = plan.intra_map(d, inverse=inverse)
     return plan.in_rows, plan.out_rows, intra.ktab, intra.words
 
 
@@ -908,8 +919,10 @@ def tiled_permute(x: jax.Array, plan: TilePlan, *,
                   ("out_rows", plan.out_rows, n_rows),
                   ("xor_low", plan.xor_low, plan.row_len),
                   ("src0", plan.src0, plan.rows_per_tile * plan.row_len)], x)
-    return tiled_permute_tables(x, *plan_tables(plan),
-                                geometry=plan_geometry(plan), batched=batched)
+    d = item_width(x, batched)
+    return tiled_permute_tables(x, *plan_tables(plan, d=d),
+                                geometry=plan_geometry(plan, d=d),
+                                batched=batched)
 
 
 # ---------------------------------------------------------------------------
@@ -939,8 +952,7 @@ def block_permute_tables(x: jax.Array, src_rows, *, geometry: tuple,
     block of ``2^b * d`` elements is viewed as (sublanes, 128 lanes)
     when it fills whole lane rows, so the block shape is a whole tile."""
     n, b, n_rows = geometry
-    lead = 1 if batched else 0
-    d = x.shape[1 + lead] if x.ndim == 2 + lead else 1
+    d = item_width(x, batched)
     blk = (1 << b) * d
     check_lanes(blk)
     inner = (blk // 128, 128) if blk % 128 == 0 else (1, blk)
@@ -1001,8 +1013,7 @@ def lane_permute_tables(x: jax.Array, src_lane, *, geometry: tuple,
     n, t, rpb = geometry
     row_len = 1 << t
     n_rows = 1 << (n - t)
-    lead = 1 if batched else 0
-    d = x.shape[1 + lead] if x.ndim == 2 + lead else 1
+    d = item_width(x, batched)
     lanes = row_len * d
     check_lanes(lanes)
     row_view = (n_rows, lanes)

@@ -28,15 +28,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.bmmc import Bmmc
-from ..core.tiling import (class_stats, copy_descriptors, dispatch_kernel,
-                           plan_block, plan_bmmc, plan_lane)
+from ..core.tiling import (IntraSteps, class_stats, copy_descriptors,
+                           dispatch_kernel, plan_block, plan_bmmc, plan_lane)
 from ..obs import metrics as _ometrics
 from ..obs import trace as _otrace
 from . import ref as _ref
 from .bmmc_permute import (block_geometry, block_permute,
                            block_permute_tables, check_lanes,
-                           geometry_descriptors, lane_geometry, lane_permute,
-                           lane_permute_tables, plan_geometry, plan_tables,
+                           geometry_descriptors, item_width, lane_geometry,
+                           lane_permute, lane_permute_tables, plan_geometry,
                            tiled_permute, tiled_permute_tables)
 
 # VMEM working-set budget for one tile buffer. The double-buffered pipeline
@@ -126,8 +126,7 @@ def class_dispatch(x: jax.Array, bmmc: Bmmc, t: Optional[int],
     per-kernel / per-class counters and the modeled descriptor /
     round-trip totals — recorded at dispatch/trace time, from offline
     plans, with no device interaction."""
-    lead = 1 if batched else 0
-    d = x.shape[1 + lead] if x.ndim == 2 + lead else 1
+    d = item_width(x, batched)
     teff = choose_tile(bmmc.n, x.dtype.itemsize, d, t)
     if teff is None:
         # the interpreter takes the gather oracle; the chip refuses
@@ -141,34 +140,39 @@ def class_dispatch(x: jax.Array, bmmc: Bmmc, t: Optional[int],
         tx = modeled_transactions(bmmc, teff, x.dtype.itemsize)
         tiled = got[0] not in ("none", "block", "lane")
         record_dispatch(got[0], bmmc.bmmc_class(teff), tx["descriptors"],
-                        tx["passes"], got[1] if tiled else ())
+                        tx["passes"], [plan_geometry(p, d=d) for p in got[1]]
+                        if tiled else ())
     return got
 
 
 def record_dispatch(kernel: str, cls: str, descriptors: int,
-                    round_trips: int, plans=(), geometries=()) -> None:
+                    round_trips: int, geometries=()) -> None:
     """The counters of one dispatch decision: ``dispatch.kernel``,
     ``dispatch.class``, the modeled ``dma.descriptors`` and
-    ``model.round_trips``, and the box sides of its tiled passes, given
-    as plans or as their :func:`plan_geometry`."""
+    ``model.round_trips``, and :func:`count_tiled_passes` of its tiled
+    passes, given as their :func:`plan_geometry`."""
     _ometrics.inc("dispatch.kernel", kernel=kernel)
     _ometrics.inc("dispatch.class", cls=cls)
     _ometrics.inc("dma.descriptors", descriptors)
     _ometrics.inc("model.round_trips", round_trips)
-    count_box_sides(plans, geometries)
+    count_tiled_passes(geometries)
 
 
-def count_box_sides(plans=(), geometries=()) -> None:
-    """``dma.box_sides{side}``: one count per side of each tiled pass
-    whose rows form a box (one strided descriptor per tile). A plan's
-    box is ``in_box`` / ``out_box``; a geometry's, a side whose layout
-    is a tuple of runs."""
-    layouts = [(p.in_box, p.out_box) for p in plans]
-    layouts += [g[3:5] for g in geometries]
-    for pair in layouts:
-        for side, layout in zip(("in", "out"), pair):
+def count_tiled_passes(geometries) -> None:
+    """The counters of tiled passes, each given as its
+    :func:`plan_geometry`: ``dma.box_sides{side}``, one count per side
+    whose rows form a box (a layout that is a tuple of runs: one
+    strided descriptor per tile), and the intra-tile schedule:
+    ``intra.xor_moves`` (XOR moves emitted), ``intra.maps`` (one-hot
+    maps) and ``intra.transposed`` (passes that transpose the tile)."""
+    for g in geometries:
+        for side, layout in zip(("in", "out"), g[3:5]):
             if isinstance(layout, tuple) and layout:
                 _ometrics.inc("dma.box_sides", side=side)
+        steps = g[7]
+        _ometrics.inc("intra.xor_moves", steps.xor_moves)
+        _ometrics.inc("intra.maps", steps.maps)
+        _ometrics.inc("intra.transposed", int(steps.transpose))
 
 
 def bmmc_permute(x: jax.Array, bmmc: Bmmc, *, t: Optional[int] = None,
@@ -208,23 +212,31 @@ def bmmc_permute(x: jax.Array, bmmc: Bmmc, *, t: Optional[int] = None,
     return x
 
 
-def _shared_geometry(passes) -> tuple:
-    """One :func:`plan_geometry` that serves every plan of ``passes``
-    (one pass of one matrix under different complements, which move
-    row ids but not the tile structure). A side keeps its box where
-    every plan has that box, else takes the shortest run any plan has:
-    runs are powers of two, so a shorter run is also a run of every
-    longer one. An intra-tile step runs where any plan needs it; with
-    its identity parameters it moves nothing."""
-    geoms = [plan_geometry(p) for p in passes]
+def _shared_pass(passes, d: int) -> tuple:
+    """``(geometry, tables)``: one :func:`plan_geometry` that serves
+    every plan of ``passes`` (one pass of one matrix under different
+    complements, which move row ids but not the tile structure) and
+    each plan's tables for it. A side keeps its box where every plan
+    has that box, else takes the shortest run any plan has: runs are
+    powers of two, so a shorter run is also a run of every longer one.
+    The plans share the intra-tile orientation whose
+    :meth:`IntraSteps.union` costs least; a move or map runs where any
+    plan needs it, and with its plan's zero words it moves nothing."""
+    geoms = [plan_geometry(p, d=d) for p in passes]
     g0 = geoms[0]
     assert all(g[:3] + g[5:7] == g0[:3] + g0[5:7] for g in geoms), geoms
     sides = tuple(
         g0[k] if len({g[k] for g in geoms}) == 1 else min(runs)
         for k, runs in ((3, [p.in_run for p in passes]),
                         (4, [p.out_run for p in passes])))
-    steps = tuple(any(on) for on in zip(*(g[7] for g in geoms)))
-    return g0[:3] + sides + g0[5:7] + (steps,)
+    options = [ims for ims in zip(*(p.intra_options for p in passes))
+               if d == 1 or not ims[0].steps.transpose]
+    intras = min(options, key=lambda ims: IntraSteps.union(
+        im.steps for im in ims).cost())
+    steps = IntraSteps.union(im.steps for im in intras)
+    tables = [(p.in_rows, p.out_rows, im.ktab, im.words)
+              for p, im in zip(passes, intras)]
+    return g0[:3] + sides + g0[5:7] + (steps,), tables
 
 
 def _pick(tables, index) -> jax.Array:
@@ -247,7 +259,7 @@ def bmmc_permute_complements(x: jax.Array, rows: tuple, cs: tuple,
     if len(set(cs)) == 1:
         return bmmc_permute(x, Bmmc(rows, cs[0]))
     n = len(rows)
-    d = x.shape[1] if x.ndim == 2 else 1
+    d = item_width(x)
     t = choose_tile(n, x.dtype.itemsize, d)
     if t is None:
         check_lanes(d)
@@ -259,8 +271,9 @@ def bmmc_permute_complements(x: jax.Array, rows: tuple, cs: tuple,
         kernel, got = "tiled", [("tiled", _plans_cached(rows, c, t))
                                 for c in cs]
     payloads = [p for _, p in got]
-    geoms = [] if kernel in ("block", "lane") else [
-        _shared_geometry(passes) for passes in zip(*payloads)]
+    shared = [] if kernel in ("block", "lane") else [
+        _shared_pass(passes, d) for passes in zip(*payloads)]
+    geoms = [g for g, _ in shared]
     if _otrace._state.enabled:
         descriptors = (sum(geometry_descriptors(g) for g in geoms) if geoms
                        else payloads[0].dma_descriptors())
@@ -274,9 +287,8 @@ def bmmc_permute_complements(x: jax.Array, rows: tuple, cs: tuple,
         return lane_permute_tables(
             x, _pick([p.src_lane for p in payloads], index),
             geometry=lane_geometry(payloads[0]))
-    for k, geometry in enumerate(geoms):
-        tables = zip(*(plan_tables(passes[k]) for passes in payloads))
-        x = tiled_permute_tables(x, *(_pick(v, index) for v in tables),
+    for geometry, tables in shared:
+        x = tiled_permute_tables(x, *(_pick(v, index) for v in zip(*tables)),
                                  geometry=geometry)
     return x
 

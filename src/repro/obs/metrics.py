@@ -21,6 +21,10 @@ Counter vocabulary used by the executor stack (DESIGN.md §12):
 * ``dma.box_sides{side=in|out}`` — tiled-pass sides dispatched whose
   rows form a box, each copied by one strided descriptor per tile
   (``TilePlan.in_box`` / ``out_box``).
+* ``intra.xor_moves`` / ``intra.maps`` / ``intra.transposed`` — per
+  tiled pass dispatched, the XOR moves its intra-tile schedule emits,
+  the one-hot maps it runs, and 1 where it transposes the tile
+  (``IntraSteps``).
 * ``dist.rounds{kind=local|permute|exchange}`` /
   ``dist.bytes{kind=all_to_all|ppermute}`` — the rounds of a sharded
   program (``core/distributed.py``) and the bytes its plan sends

@@ -356,3 +356,31 @@ def test_bwd_megakernel_matches_collapsed(monkeypatch):
     monkeypatch.setattr(EX, "BWD_MEGAKERNEL", True)
     g_mega = grad()
     assert np.allclose(g_mega, g_default, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.tier1
+@pytest.mark.parametrize("megakernel", [False, True])
+def test_sort_grad_through_transposed_tiles(monkeypatch, megakernel):
+    """The 2^8 sort has a cluster whose tile transposes, forward and
+    inverse, and clusters with sparse shears: its gradient, stage by
+    stage through each cluster's collapsed backward or its gradient
+    megakernel, equals the ref engine's bit for bit."""
+    t = choose_tile(N, 4)
+    f = compile_expr(sort_expr(N), engine="pallas")
+    plans = [ex_plan for s in f.clustered_program(N, t)
+             if isinstance(s, FusedStage) and s.computes
+             for ex_plan in EX._fused_plan_cached(s, t)[0]]
+    assert any(p.intra.steps.transpose and p.intra_inv.steps.transpose
+               for p in plans)
+    monkeypatch.setattr(EX, "BWD_MEGAKERNEL", megakernel)
+    kernels = []
+    real = EX._fused_bwd_pallas
+    monkeypatch.setattr(EX, "_fused_bwd_pallas",
+                        lambda *a: kernels.append(a) or real(*a))
+    clear_caches()
+    x, w = _x(N, 31), _x(N, 32)
+    ref = compile_expr(sort_expr(N), engine="ref")
+    g = jax.grad(lambda v: jnp.sum(w * f.call_per_stage(v)))(x)
+    want = jax.grad(lambda v: jnp.sum(w * ref(v)))(x)
+    assert np.asarray(g).tobytes() == np.asarray(want).tobytes()
+    assert bool(kernels) == megakernel

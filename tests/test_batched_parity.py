@@ -17,7 +17,7 @@ from hypothesis import given, settings, strategies as st
 from repro.combinators import cache_stats, clear_caches, compile_expr
 from repro.combinators import vocab as V
 from repro.core.bmmc import Bmmc
-from repro.kernels.ops import bmmc_permute
+from repro.kernels.ops import bmmc_permute, choose_tile, class_plan
 from repro.kernels.ref import bmmc_ref
 
 DTYPES = (jnp.int32, jnp.float32, jnp.bfloat16)
@@ -129,3 +129,21 @@ def test_batched_roundtrip_through_tiled_kernels():
     finv = f.inverse(n)
     x = _payload((4, 1 << n), jnp.float32, 5)
     _assert_same(finv(f(x, batched=True), batched=True), x, "roundtrip")
+
+
+@pytest.mark.tier1
+@pytest.mark.parametrize("seed", [0, 1])
+def test_batched_transposed_tiles(seed):
+    """(B, 2^n) through a BPC pass whose tiles transpose, with sparse
+    shears (seed 0) or none (seed 1), == the per-row ref gather, bit
+    for bit."""
+    n = 10
+    rng = random.Random(seed)
+    b = Bmmc(Bmmc.random_bpc(n, rng).rows, rng.getrandbits(n))
+    (plan,) = class_plan(b, choose_tile(n, 4))[1]
+    steps = plan.intra.steps
+    assert steps.transpose and steps.xor_moves == (6, 0)[seed]
+    x = _payload((3, 1 << n), jnp.int32, seed)
+    got = bmmc_permute(x, b, engine="pallas", batched=True)
+    want = jnp.stack([bmmc_ref(x[i], b) for i in range(3)])
+    _assert_same(got, want, seed)

@@ -107,6 +107,10 @@ def test_set_up_and_window_readings_on_a_recorded_trace(tmp_path):
         k["s"] for k in setup["plan_kinds"].values())
     assert setup["dma_descriptors_per_call"] > 0
     assert setup["dma_box_sides_per_call"]["in"] > 0
+    (intra,) = setup["intra_per_call"].values()
+    assert set(intra) == {"xor_moves", "maps", "transposed"}
+    assert intra["maps"] > 0 and intra["xor_moves"] > 0
+    assert intra["transposed"] >= 1   # one cluster of the 2^8 sort
     assert not obs.enabled() and obs.events() == []
 
     win = harness.Window(calls, xs, mix, 1)

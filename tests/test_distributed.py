@@ -145,12 +145,14 @@ def test_reshard_mix_plans(name, exchanges, permutes, locals_):
 
 def _complement_cases():
     """(rows, complements) per kernel class the class dispatch picks
-    for ``rows``; the identity's complements span every class."""
+    for ``rows``; the identity's complements span every class. The
+    bit-reverse's tiled pass runs on the transposed tile."""
     n, rng = 10, random.Random(5)
     block = list(range(n))
     block[n - 1], block[7] = block[7], block[n - 1]
     lane = list(reversed(range(5))) + list(range(5, n))
     cases = {
+        "transposed": Bmmc.bit_reverse(n).rows,
         "tiled": Bmmc.random_bpc(n, rng).rows,
         "general": Bmmc.random(n, rng).rows,
         "block": Bmmc.from_perm(block).rows,
@@ -164,12 +166,25 @@ def _complement_cases():
 @pytest.mark.parametrize("kind", sorted(_complement_cases()))
 def test_permute_complements_by_index(kind):
     """One kernel, its tables chosen by a traced index, equals the
-    reference for each complement."""
+    reference for each complement; the complements' tiled passes share
+    one orientation and the union of their intra-tile schedules."""
     import jax
     import jax.numpy as jnp
+    from repro.core.tiling import IntraSteps
     from repro.kernels import ops
     from repro.kernels.ref import bmmc_ref
     rows, cs = _complement_cases()[kind]
+    t = ops.choose_tile(len(rows), 4)
+    got = [ops.class_plan(Bmmc(rows, c), t) for c in cs]
+    if got[0][0] not in ("block", "lane") and len({k for k, _ in got}) == 1:
+        for passes in zip(*(pay for _, pay in got)):
+            geometry, tables = ops._shared_pass(passes, 1)
+            chosen = [next(im for im in p.intra_options
+                           if im.ktab is tab[2] and im.words is tab[3])
+                      for p, tab in zip(passes, tables)]
+            assert geometry[7] == IntraSteps.union(
+                im.steps for im in chosen)
+            assert geometry[7].transpose == (kind == "transposed")
     x = jnp.arange(1 << len(rows), dtype=jnp.int32)
     fn = jax.jit(lambda v, i: ops.bmmc_permute_complements(v, rows, cs, i))
     for i, c in enumerate(cs):

@@ -134,39 +134,57 @@ def _row_runs(geometry, plan):
     return geometry[:3] + (plan.in_run, plan.out_run) + geometry[5:]
 
 
+def _oriented(geometry, plan, intra):
+    """The pass run through one given intra-tile factorization (either
+    orientation): its geometry and tables."""
+    return (geometry[:7] + (intra.steps,),
+            (plan.in_rows, plan.out_rows, intra.ktab, intra.words))
+
+
 @pytest.mark.parametrize("layout", ["flat", "batched", "planar"])
 @pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32, jnp.bfloat16])
 def test_box_and_row_dma_paths_match_ref(dtype, layout):
     """Box sides (a BPC with a complement: multi-run boxes on both sides)
     and sides that are no box (a random BMMC) match ``kernels/ref.py``
-    bit for bit, forward and through the gradient kernel; the BPC also
+    bit for bit, forward and through the gradient kernel, in every
+    orientation of the intra-tile factorization a layout may take (1-wide
+    items also transpose the tile) with its sparse shears; the BPC also
     with its sides forced onto the per-run path."""
     n, t = 10, 4
     rng = random.Random(11)
     bpc = Bmmc(Bmmc.random_bpc(n, rng).rows, rng.getrandbits(n))
     bmmc = Bmmc.random(n, rng)
     batched = layout == "batched"
+    d = 2 if layout == "planar" else 1
     shape = {"flat": (1 << n,), "batched": (3, 1 << n),
              "planar": (1 << n, 2)}[layout]
     x = jnp.asarray(np.random.default_rng(0).integers(
         -2**15, 2**15, size=shape)).astype(dtype)
     ct = x[::-1] if not batched else x[:, ::-1]
+    orients = set()
     for b in (bpc, bmmc):
         (plan,) = plan_bmmc(b, t)
         assert (plan.in_box is not None) == (b is bpc)
-        geoms = [(bp.plan_geometry(plan),
-                  bp.plan_geometry(plan, inverse=True))]
+        geoms = [(bp.plan_geometry(plan, d=d),
+                  bp.plan_geometry(plan, inverse=True, d=d))]
         if b is bpc:
             assert plan.out_box is not None
             geoms.append((_row_runs(geoms[0][0], plan),
                           _row_runs(geoms[0][1], plan)))
+        intras = [(f, i) for f, i in zip(plan.intra_options,
+                                         plan.intra_inv_options)
+                  if d == 1 or not f.steps.transpose]
         want = np.asarray(bmmc_ref(x, b, batched=batched))
         want_ct = np.asarray(bmmc_ref(ct, b.inverse(), batched=batched))
         for fwd, bwd in geoms:
-            got = bp.tiled_permute_tables(x, *bp.plan_tables(plan),
-                                          geometry=fwd, batched=batched)
-            assert np.asarray(got).tobytes() == want.tobytes()
-            got = bp.tiled_permute_bwd_tables(
-                x, ct, *bp.plan_tables(plan, inverse=True), geometry=bwd,
-                batched=batched)
-            assert np.asarray(got).tobytes() == want_ct.tobytes()
+            for f_intra, i_intra in intras:
+                orients.add(f_intra.steps.transpose)
+                geom, tables = _oriented(fwd, plan, f_intra)
+                got = bp.tiled_permute_tables(x, *tables, geometry=geom,
+                                              batched=batched)
+                assert np.asarray(got).tobytes() == want.tobytes()
+                geom, tables = _oriented(bwd, plan, i_intra)
+                got = bp.tiled_permute_bwd_tables(
+                    x, ct, *tables, geometry=geom, batched=batched)
+                assert np.asarray(got).tobytes() == want_ct.tobytes()
+    assert orients == ({False, True} if d == 1 else {False})

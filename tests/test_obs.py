@@ -126,7 +126,9 @@ def test_sort_counters_match_program_cost():
 @pytest.mark.tier1
 def test_box_side_counter_matches_plans():
     """A cold sort call counts ``dma.box_sides`` per side: exactly the
-    tiled-pass sides of its plans whose rows form a box."""
+    tiled-pass sides of its plans whose rows form a box; and the XOR
+    moves, one-hot maps and transposed tiles of its passes' intra-tile
+    schedules (``intra.*``)."""
     from repro.combinators import execute as ex
     from repro.combinators.ir import Perm
     from repro.combinators.optimize import FusedStage
@@ -151,17 +153,26 @@ def test_box_side_counter_matches_plans():
     got = {side: obs.counter_value("dma.box_sides", side=side)
            for side in want}
     assert got == want
+    steps = [p.intra.steps for p in plans]
+    assert obs.counter_total("intra.xor_moves") == sum(
+        s.xor_moves for s in steps)
+    assert obs.counter_total("intra.maps") == sum(s.maps for s in steps)
+    assert obs.counter_total("intra.transposed") == sum(
+        s.transpose for s in steps)
 
 
 def _stacked_case(kind: str) -> tuple:
-    """(rows, complements) of one stacked-table pass: a random BPC or
-    a random BMMC at 2^10, or the local round of a sharded 2^11 plan
-    whose shards' complements give the tiled passes different runs."""
+    """(rows, complements) of one stacked-table pass: a bit-reverse (a
+    transposed tile), a random BPC or a random BMMC at 2^10, or the
+    local round of a sharded 2^11 plan whose shards' complements give
+    the tiled passes different runs."""
     from repro.core import distributed as D, f2
     from repro.kernels import ops
     from repro.kernels.bmmc_permute import plan_geometry
     rng = random.Random(5)
     cs = (0, 0b11 << 7, 0b101, (0b11 << 7) | 0b101)
+    if kind == "bitrev":
+        return Bmmc.bit_reverse(10).rows, cs
     if kind != "shard-round":
         draw = Bmmc.random_bpc if kind == "bpc" else Bmmc.random
         return draw(10, rng).rows, cs
@@ -180,12 +191,13 @@ def _stacked_case(kind: str) -> tuple:
 
 
 @pytest.mark.tier1
-@pytest.mark.parametrize("kind", ["bpc", "bmmc", "shard-round"])
+@pytest.mark.parametrize("kind", ["bpc", "bmmc", "shard-round", "bitrev"])
 def test_stacked_descriptor_counter_matches_kernel(monkeypatch, kind):
     """A pass whose tables are stacked per complement (a sharded local
-    round) counts ``dma.descriptors`` and ``dma.box_sides`` as the
-    kernels it launches issue them: per tile, each side's ``_Side``
-    count under the geometry the kernel is given."""
+    round) counts ``dma.descriptors``, ``dma.box_sides`` and the
+    ``intra.*`` schedule counters as the kernels it launches run them:
+    per tile, each side's ``_Side`` count under the geometry the kernel
+    is given, and that geometry's intra-tile schedule."""
     from repro.kernels import bmmc_permute as bp
     from repro.kernels import ops
     rows, cs = _stacked_case(kind)
@@ -210,6 +222,13 @@ def test_stacked_descriptor_counter_matches_kernel(monkeypatch, kind):
     assert {side: obs.counter_value("dma.box_sides", side=side)
             for side in boxes} == boxes
     assert obs.counter_total("model.round_trips") == len(launched)
+    steps = [g[7] for g in launched]
+    assert obs.counter_total("intra.xor_moves") == sum(
+        s.xor_moves for s in steps)
+    assert obs.counter_total("intra.maps") == sum(s.maps for s in steps)
+    assert obs.counter_total("intra.transposed") == sum(
+        s.transpose for s in steps)
+    assert any(s.transpose for s in steps) == (kind == "bitrev")
 
 
 @pytest.mark.tier1

@@ -77,11 +77,15 @@ def _has_kernel(text: str) -> bool:
 _BOX_RUNS = {"bit_reverse": (1, 1), "bpc": (5, 3), "bmmc": (None, None)}
 
 
-@pytest.mark.parametrize("name", sorted(_BOX_RUNS))
-def test_tiled_pass_compiles(chip, name):
+@pytest.mark.parametrize("name,transpose", [
+    pytest.param(name, tr, id=name + ("-transposed" if tr else ""))
+    for name in sorted(_BOX_RUNS) for tr in (False, True)])
+def test_tiled_pass_compiles(chip, name, transpose):
     """The paper suite's passes: bit-reverse and the random BPC of
     ``paper_fig9.cases`` take strided box descriptors on both sides, the
-    random BMMC per-row descriptors."""
+    random BMMC per-row descriptors; each with its intra-tile gather
+    factored in either orientation (the transposed one transposes the
+    512 x 512 tile first)."""
     b = {"bit_reverse": lambda: Bmmc.bit_reverse(N),
          "bpc": lambda: Bmmc.random_bpc(N, random.Random(42)),
          "bmmc": lambda: Bmmc.random(N, random.Random(7))}[name]()
@@ -89,9 +93,12 @@ def test_tiled_pass_compiles(chip, name):
     (plan,) = plan_bmmc(b, t)
     assert tuple(box and len(box) for box in (plan.in_box, plan.out_box)) \
         == _BOX_RUNS[name]
-    fn = functools.partial(bp.tiled_permute_tables,
-                           geometry=bp.plan_geometry(plan))
-    tables = bp.plan_tables(plan)
+    (intra,) = [im for im in plan.intra_options
+                if im.steps.transpose == transpose]
+    fn = functools.partial(
+        bp.tiled_permute_tables,
+        geometry=bp.plan_geometry(plan)[:7] + (intra.steps,))
+    tables = (plan.in_rows, plan.out_rows, intra.ktab, intra.words)
     assert _has_kernel(chip(lambda x: fn(x, *tables), ((1 << N,), jnp.int32)))
 
 
@@ -122,11 +129,11 @@ def _cluster(prog, kind, want: int):
     raise AssertionError("no such cluster")
 
 
-def _fused_pass(fs, t, dtype, inverse=False):
+def _fused_pass(fs, t, dtype, inverse=False, d=1):
     plans, entries = ex._fused_plan_cached(fs, t)
     sig, scal, vmem, map_fns = ex._fused_kernel_args(entries, dtype)
-    geom = bp.plan_geometry(plans[0], inverse=inverse)
-    tables = bp.plan_tables(plans[0], inverse=inverse)
+    geom = bp.plan_geometry(plans[0], inverse=inverse, d=d)
+    tables = bp.plan_tables(plans[0], inverse=inverse, d=d)
     kern = bp.tiled_permute_bwd_tables if inverse else bp.tiled_permute_tables
     fn = functools.partial(kern, geometry=geom, epilogue=sig,
                            map_fns=map_fns)
@@ -151,17 +158,22 @@ def test_fused_fft_bfly_cluster_compiles(chip):
     t = choose_tile(n, 4, 2)
     fs = _cluster(compiled_fft(n, engine="pallas").clustered_program(n, t),
                   Bfly, 2)
-    fn, tables, scal, vmem = _fused_pass(fs, t, jnp.float32)
+    fn, tables, scal, vmem = _fused_pass(fs, t, jnp.float32, d=2)
     text = chip(lambda x: fn(x, *tables, epi_scalar=scal, epi_vmem=vmem),
                 ((1 << n, 2), jnp.float32))
     assert _has_kernel(text)
 
 
-def test_gradient_kernel_with_cmp_compiles(chip):
+@pytest.mark.parametrize("transpose", [False, True])
+def test_gradient_kernel_with_cmp_compiles(chip, transpose):
+    """The gradient megakernel of a sort cluster: the first with three
+    compares, and the first whose inverse gather transposes the tile."""
     n = 20
     t = choose_tile(n, 4)
-    fs = _cluster(compiled_sort(n, engine="pallas").clustered_program(n, t),
-                  CmpHalves, 3)
+    prog = compiled_sort(n, engine="pallas").clustered_program(n, t)
+    fs = (next(s for s in prog if isinstance(s, FusedStage) and s.computes
+               and ex._fused_plan_cached(s, t)[0][0].intra_inv.steps.transpose)
+          if transpose else _cluster(prog, CmpHalves, 3))
     fn, tables, scal, vmem = _fused_pass(fs, t, jnp.float32, inverse=True)
     x = ((1 << n,), jnp.float32)
     text = chip(lambda v, c: fn(v, c, *tables, epi_scalar=scal,
