@@ -8,8 +8,9 @@ One chip runs, through the public entry points and with ``repro.obs``
 recording:
 
   (a) the paper's suite at 2^24 int32: the copy baseline, bit-reverse,
-      random BPC and random BMMC (the ``benchmarks/paper_fig9.py``
-      cases) and one block- and one lane-class BMMC, each through
+      random BPC and random BMMC (the matrices of the ``suite-tiled-24``
+      cell, read from ``bench/traffic/suite_round_robin.json``) and one
+      block- and one lane-class BMMC, each through
       ``ops.make_bmmc_permute``;
   (b) the compiled sort and FFT of 2^20 elements, forward and under
       ``jax.value_and_grad``;
@@ -44,6 +45,8 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 SEED = 42
 
 N_SUITE = 24        # paper suite, int32
+SUITE_TRAFFIC = os.path.join(ROOT, "bench", "traffic",
+                             "suite_round_robin.json")
 N_SORT = 20         # compiled sort (f32) and FFT (planar f32)
 # value_and_grad of the sort: its collapsed backward bakes three 2^n
 # int32 tables per compare link into the program, which passes the
@@ -135,13 +138,18 @@ def run_phase(phase: str, fn, args: tuple, kernels: dict, exact=True):
 # ---------------------------------------------------------------------------
 
 def suite_cases(n: int, t: int) -> list:
-    """(name, BMMC, kernel class) of the suite at 2^n with tile t."""
-    sys.path.insert(0, ROOT)
-    from benchmarks.paper_fig9 import cases
+    """(name, BMMC, kernel class) of the suite at 2^n with tile t: the
+    ``suite-tiled-24`` cell's three matrices, then a block swap and a
+    lane reverse."""
     from repro.core.bmmc import Bmmc
     kinds = {"bit-reverse": "tiled", "random-bpc": "tiled",
              "random-bmmc": "general"}
-    out = [(name, b, kinds[name]) for name, b in cases(n)]
+    with open(SUITE_TRAFFIC) as f:
+        ops = json.load(f)["ops"]
+    out = [(op["name"], Bmmc(tuple(op["rows"]), op["c"]), kinds[op["name"]])
+           for op in ops]
+    check(all(b.n == n for _, b, _ in out),
+          f"{SUITE_TRAFFIC}: matrices are not of 2^{n} elements")
     perm = list(range(n))          # swap a high bit pair: whole blocks move
     perm[n - 1], perm[t + 2] = perm[t + 2], perm[n - 1]
     out.append(("block-swap", Bmmc.from_perm(perm), "block"))

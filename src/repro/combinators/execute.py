@@ -1444,9 +1444,10 @@ class CompiledExpr:
 
     def call_per_stage(self, x: jax.Array, *,
                        batched: bool = False) -> jax.Array:
-        """Execute stage-at-a-time through the Python dispatcher —
-        the pre-executable path, kept for the host-side dispatch-
-        overhead microbenchmark and as a debugging aid."""
+        """Execute stage-at-a-time through the Python dispatcher, with
+        no whole-program executable. Tests check ``__call__`` against it
+        bitwise and drive the per-stage backward through it; it is also
+        a debugging aid."""
         prog = self._resolve_program(x, batched)
         return run_program(prog, x, self.engine, batched=batched)
 

@@ -360,8 +360,8 @@ def tiled_columns(rows: Rows, t: int, prefer_contiguous: bool = True) -> Optiona
 
 
 # ---------------------------------------------------------------------------
-# Random generation (for tests / benchmarks; mirrors the paper's "random
-# BPC / random BMMC" experiments)
+# Random generation (for tests and the benchmark's matrices; mirrors the
+# paper's "random BPC / random BMMC" experiments)
 # ---------------------------------------------------------------------------
 
 def random_invertible(n: int, rng: random.Random) -> Rows:

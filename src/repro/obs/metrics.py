@@ -68,10 +68,10 @@ Counter vocabulary used by the executor stack (DESIGN.md §12):
 * ``store.version_skew`` — entries from an older schema or planner
   generation: a plain miss (legal, just unusable), overwritten by the
   rebuild, never quarantined.
-* ``store.plan_built{kind=...}`` — plans built from scratch (the CI
-  warm-start gate asserts this stays 0 on a disk-warm boot).
-* ``store.warmstart_us{workload=...}`` — first-call latency histogram
-  of disk-warm boots (benchmarks/store_warmstart.py).
+* ``store.plan_built{kind=...}`` — plans built from scratch (stays 0 on
+  a disk-warm boot: ``tests/test_store.py``
+  ``test_warm_boot_compiles_zero_plans`` and
+  ``test_two_process_persistence_roundtrip``).
 
 * ``resilience.breaker.open{engine=...}`` /
   ``resilience.breaker.probe{engine=...}`` /

@@ -292,7 +292,7 @@ print("OK", s["hit"], s["plan_built"])
 """
 
 
-@pytest.mark.slow
+@pytest.mark.tier1
 def test_two_process_persistence_roundtrip(tmp_path):
     root = str(tmp_path / "planstore")
     env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",

@@ -224,7 +224,8 @@ def test_no_plan_emits_more_moves_than_before(n, seed, kind):
 
 def test_suite_intra_moves_at_24():
     """At n = 24, t = 9 the paper suite's bit-reverse emits no XOR move
-    and its random BPC (``paper_fig9.cases``) at most 3, both on the
+    and its random BPC (``suite-tiled-24``'s, the first
+    ``random_bpc`` draw of ``random.Random(42)``) at most 3, both on the
     transposed tile; the inverse gathers too."""
     t = choose_tile(24, 4)
     for b, most in ((Bmmc.bit_reverse(24), 0),

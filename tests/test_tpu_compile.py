@@ -81,8 +81,8 @@ _BOX_RUNS = {"bit_reverse": (1, 1), "bpc": (5, 3), "bmmc": (None, None)}
     pytest.param(name, tr, id=name + ("-transposed" if tr else ""))
     for name in sorted(_BOX_RUNS) for tr in (False, True)])
 def test_tiled_pass_compiles(chip, name, transpose):
-    """The paper suite's passes: bit-reverse and the random BPC of
-    ``paper_fig9.cases`` take strided box descriptors on both sides, the
+    """The paper suite's passes: bit-reverse and the random BPC of the
+    ``suite-tiled-24`` cell take strided box descriptors on both sides, the
     random BMMC per-row descriptors; each with its intra-tile gather
     factored in either orientation (the transposed one transposes the
     512 x 512 tile first)."""
